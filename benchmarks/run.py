@@ -44,8 +44,12 @@ def main() -> None:
         args.repeats = 2
         args.full = False
 
+    from repro.compile_cache import enable_compile_cache
+
     from . import (alloc_bench, collective_bench, lock_bench, put_get,
                    teamlist_bench)
+
+    enable_compile_cache()
 
     slow_repeats = args.repeats if args.quick else max(args.repeats, 50)
     suites = {

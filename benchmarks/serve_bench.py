@@ -188,6 +188,8 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     serving = run(quick=args.quick, seed=args.seed)
 
     jpath = OUT_DIR / "BENCH_engine.json"

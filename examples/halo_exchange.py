@@ -22,7 +22,10 @@ reference.
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DartConfig, dart_exit, dart_init, shm_supported
+
+enable_compile_cache()
 
 N_UNITS = 8
 LOCAL = 32                      # cells per unit

@@ -20,7 +20,10 @@ and the per-step dispatch trajectory is asserted: 8 column halos ride
 import numpy as np
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DartConfig, NArray, TileDist, dart_exit, dart_init
+
+enable_compile_cache()
 
 GR, GC = 2, 2                    # unit grid
 TR, TC = 8, 8                    # tile shape

@@ -17,6 +17,9 @@ import numpy as np
 from repro.core import (DART_TEAM_ALL, DartConfig, dart_barrier, dart_exit,
                         dart_init, dart_team_create, dart_team_myid,
                         group_from_units)
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. initialize a runtime with 8 units -----------------------------------
 ctx = dart_init(n_units=8, config=DartConfig())

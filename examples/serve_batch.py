@@ -19,11 +19,13 @@ sys.path.insert(0, str(pathlib.Path(__file__).parent.parent / "src"))
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.models import api
 from repro.models.config import reduced_for_smoke
 from repro.serve import ContinuousEngine, Request, ServeEngine
 
+enable_compile_cache()
 cfg = reduced_for_smoke(get_config("llama3-8b"))
 params = api.init_params(cfg, jax.random.PRNGKey(0))
 

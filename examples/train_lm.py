@@ -18,6 +18,7 @@ import sys
 
 sys.path.insert(0, str(pathlib.Path(__file__).parent.parent / "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import main as train_main
 
 
@@ -26,6 +27,7 @@ def run(argv=None):
     ap.add_argument("--full-100m", action="store_true")
     ap.add_argument("--ckpt", default="/tmp/repro_train_lm_ckpt")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     shutil.rmtree(args.ckpt, ignore_errors=True)
 

@@ -552,9 +552,14 @@ class CommEngine:
     ``impl`` selects the batched-kernel implementation (matching
     :mod:`repro.kernels.ops`): ``'ref'`` = XLA segmented scatter/
     gather, ``'pallas'`` = the hand-tiled descriptor-grid kernel,
-    ``'auto'`` = pallas on TPU, ref elsewhere.  Runs whose descriptors
-    fail the Pallas window precondition fall back to ref per-dispatch,
-    so the choice never changes semantics.
+    ``'auto'`` = ref on every backend.  The Pallas kernels map the
+    whole arena into VMEM and slice it at unaligned byte offsets, which
+    the TPU compiler refuses, so ``impl='pallas'`` raises on a TPU
+    backend and runs in interpret mode elsewhere.  Runs whose
+    descriptors fail the Pallas window precondition (or that have no
+    Pallas kernel: strided accumulates, fused fetches) fall back to ref
+    per-dispatch, so the choice never changes semantics; every such
+    fallback is counted (``impl_fallbacks``, :meth:`dispatch_stats`).
 
     **Thread safety**: ``lock`` (reentrant) guards ``_pending``, the
     counters, and the holder-state swap inside ``flush`` — submitters,
@@ -578,6 +583,10 @@ class CommEngine:
         self.ops_coalesced = 0
         self.compile_count = 0
         self.plan_cache_hits = 0
+        #: one-sided run dispatches by the kernel impl that served them
+        self.impl_dispatches: Dict[str, int] = {"ref": 0, "pallas": 0}
+        #: runs of a 'pallas' engine served by 'ref' instead
+        self.impl_fallbacks = 0
         # -- shm plane (repro.core.shm; docs/API.md "Shared-memory
         # plane") ------------------------------------------------------
         #: locked host-side writes routed through the shm window (each
@@ -616,9 +625,25 @@ class CommEngine:
         self.at_most_once_aborts = 0
         self.failed_runs = 0
         self.enqueue_rejections = 0
-        if impl == "auto":
-            impl = "pallas" if jax.default_backend() == "tpu" else "ref"
         self.impl = impl
+
+    @property
+    def impl(self) -> str:
+        return self._impl
+
+    @impl.setter
+    def impl(self, impl: str) -> None:
+        if impl == "auto":
+            impl = "ref"
+        if impl not in ("ref", "pallas"):
+            raise ValueError(f"unknown engine impl {impl!r} "
+                             "(expected 'ref', 'pallas' or 'auto')")
+        if impl == "pallas" and jax.default_backend() == "tpu":
+            raise NotImplementedError(
+                "impl='pallas' does not compile on TPU: the segmented-"
+                "copy kernels map the whole arena into VMEM and slice it "
+                "at unaligned byte offsets; use impl='ref' (or 'auto')")
+        self._impl = impl
 
     def bind(self, holder) -> None:
         self._holder = holder
@@ -759,6 +784,19 @@ class CommEngine:
             s["injector"] = plane.stats()
         return s
 
+    def dispatch_stats(self) -> Dict[str, object]:
+        """Which kernels served the one-sided runs: the engine impl,
+        dispatches per impl, Pallas→ref fallbacks, and the plan cache's
+        compile/hit counts."""
+        with self.lock:
+            return {
+                "impl": self.impl,
+                "dispatches": dict(self.impl_dispatches),
+                "impl_fallbacks": self.impl_fallbacks,
+                "compile_count": self.compile_count,
+                "plan_cache_hits": self.plan_cache_hits,
+            }
+
     def set_progress_notifier(self, cb: Optional[Callable[[], None]]
                               ) -> None:
         """Register (or clear) the enqueue callback the progress plane
@@ -777,11 +815,19 @@ class CommEngine:
         else:
             self.compile_count += 1
 
-    def _pick_impl(self, desc: np.ndarray, seg: int,
-                   pool_bytes: int) -> str:
-        if self.impl == "pallas" and _sc.pallas_ok(desc, seg, pool_bytes):
-            return "pallas"
-        return "ref"
+    def _pick_impl(self, desc: np.ndarray, seg: int, pool_bytes: int,
+                   has_pallas: bool = True) -> str:
+        """Kernel impl for one run, counted: a 'pallas' engine serves a
+        run with ref (and counts a fallback) when the run has no Pallas
+        kernel or fails the window precondition."""
+        impl = "ref"
+        if self.impl == "pallas":
+            if has_pallas and _sc.pallas_ok(desc, seg, pool_bytes):
+                impl = "pallas"
+            else:
+                self.impl_fallbacks += 1
+        self.impl_dispatches[impl] += 1
+        return impl
 
     # -- enqueue (initiation) -------------------------------------------
     def put(self, heap: SymmetricHeap, teams_by_slot, gptr: GlobalPtr,
@@ -1202,10 +1248,12 @@ class CommEngine:
             [op.payload for op in run], first.op, first.dtype,
             strides=[op.stride for op in run],
             counts=[op.count for op in run])
-        # strided RMW rides the ref kernels only: the Pallas accumulate
-        # keeps its exact kb*seg identity-slot layout (contiguous runs)
-        impl = ("ref" if any(op.count > 1 for op in run)
-                else self._pick_impl(desc, seg, int(arena.shape[1])))
+        # strided RMW and fused fetches ride the ref kernels only: the
+        # Pallas accumulate keeps its exact kb*seg identity-slot layout
+        # (contiguous runs) and returns no pre-update windows
+        impl = self._pick_impl(
+            desc, seg, int(arena.shape[1]),
+            has_pallas=not first.fetch and all(op.count == 1 for op in run))
         fn, hit = _sc.accumulate_plan(
             arena.shape, desc.shape[0], seg, flat.shape[0],
             op=first.op, dtype=first.dtype, fetch=first.fetch,

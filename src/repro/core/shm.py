@@ -7,8 +7,9 @@ sizes, intra- and inter-NUMA communication becomes a lot more
 efficient."
 
 DART-JAX analogue: when the target unit's partition is host-visible
-(CPU backend, or a TPU host reading its own chips' HBM through dlpack),
-the shm plane bypasses jitted dispatch in BOTH directions:
+(an arena in host memory: the CPU backend), the shm plane bypasses
+jitted dispatch in BOTH directions.  Arenas on an accelerator (TPU
+HBM) are never host-visible, so on the chip every op takes the engine:
 
 * **reads** — ``dart_shm_view`` returns a zero-copy numpy view of the
   addressed bytes (no dynamic-slice dispatch, no buffer copy).  The
@@ -39,11 +40,10 @@ first probed pool poisoned routing for every other pool under mixed
 visibility (host-visible CPU arena + device-only arena); it is keyed
 by poolid now.
 
-Measured effect (benchmarks/out/BENCH_engine.json, ``shm_plane``):
-the ~300 µs constant per-op jitted dispatch drops to single-digit µs
-for intra-node puts, and intra-node broadcast costs zero jitted
-dispatches — the paper's "a lot more efficient for small messages"
-expectation, now on the write side too.
+Effect on the CPU backend (benchmarks/out/BENCH_engine.json,
+``shm_plane``): an intra-node put costs zero jitted dispatches, and so
+does an intra-node broadcast — the paper's "a lot more efficient for
+small messages" expectation, on the write side too.
 """
 
 from __future__ import annotations
@@ -113,9 +113,13 @@ def _probe_pool_locked(ctx, poolid: int) -> Tuple[bool, bool]:
         ctx._shm_probe_count = getattr(ctx, "_shm_probe_count", 0) + 1
     except AttributeError:
         pass
+    # only host-memory arenas are host-visible: on an accelerator the
+    # dlpack probe may succeed by copying to the host, and the buffer
+    # pointer is a device address the host must never write through
     try:
-        np.from_dlpack(arena)
-        readable = True
+        readable = all(d.platform == "cpu" for d in arena.devices())
+        if readable:
+            np.from_dlpack(arena)
     except Exception:   # noqa: BLE001 - any failure means "not visible"
         readable = False
     writable = False
@@ -227,8 +231,8 @@ def classify_locality(ctx, gptr: GlobalPtr) -> Locality:
 
     A target is SHM_LOCAL when its pointer was minted by
     ``dart_team_memalloc_shared`` (FLAG_SHM) *and* the backing arena is
-    host-visible on this controller (CPU backend, or same-host HBM via
-    dlpack).  Everything else takes the jitted one-sided path.
+    host-visible on this controller (an arena in host memory).
+    Everything else takes the jitted one-sided path.
     """
     if not gptr.is_shm:
         return Locality.REMOTE
@@ -280,9 +284,9 @@ def dart_shm_view(ctx, gptr: GlobalPtr, shape: Tuple[int, ...],
                   dtype) -> np.ndarray:
     """Zero-copy read-only view of the addressed bytes.
 
-    Requires a FLAG_SHM pointer and a host-visible arena (CPU backend /
-    same-host HBM via dlpack).  Falls back with an explicit error
-    rather than silently copying.  The view is a **live window**: a
+    Requires a FLAG_SHM pointer and a host-visible arena (an arena in
+    host memory).  Falls back with an explicit error rather than
+    silently copying.  The view is a **live window**: a
     later ``dart_shm_put`` through the same arena is visible in it
     (writes that flush a jitted epoch re-install a NEW arena, which a
     previously taken view does not follow).

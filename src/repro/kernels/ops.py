@@ -14,8 +14,6 @@ from typing import Literal
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 from . import ref as _ref
 from .rdma import rdma_get, rdma_put
 from .ring_allgather import ring_all_gather
@@ -39,7 +37,7 @@ def make_rdma_put(mesh: jax.sharding.Mesh, axis_name: str,
         return rdma_put(x, axis_name=axis_name, num_devices=n,
                         offset=offset, interpret=_interpret_default())
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis_name, None),
         out_specs=P(axis_name, None), check_vma=False))
 
@@ -56,7 +54,7 @@ def make_ring_all_gather(mesh: jax.sharding.Mesh, axis_name: str,
                                interpret=_interpret_default())
 
     # input sharded over units; output replicated (every unit holds all)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis_name, None),
         out_specs=P(axis_name, None), check_vma=False))
 
@@ -72,6 +70,6 @@ def make_ring_reduce_scatter(mesh: jax.sharding.Mesh, axis_name: str,
         return ring_reduce_scatter(x, axis_name=axis_name, num_devices=n,
                                    interpret=_interpret_default())
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis_name, None),
         out_specs=P(axis_name, None), check_vma=False))

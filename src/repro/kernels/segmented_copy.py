@@ -68,6 +68,15 @@ semantics never depend on the impl choice.  TPU grids execute
 sequentially, so the one Pallas scatter kernel serves ordered runs
 too; strided runs widen its grid to ``(k, cb)`` — one step per
 (descriptor, segment).
+
+The Pallas kernels do not compile for TPU yet: every one maps the whole
+arena into VMEM (``BlockSpec(arena.shape)``), Mosaic refuses the
+unaligned dynamic 1-D slice of the flat payload (``flat_ref[pl.ds(st,
+sseg)]``: index not provably a multiple of 1024), and the gather's
+``(1, seg_out)`` output block breaks the (8, 128) block-shape rule.
+:class:`~repro.core.onesided.CommEngine` therefore refuses
+``impl='pallas'`` on a TPU backend.  Elsewhere they run in interpret
+mode, and the differential suites hold them to the ref kernels.
 """
 
 from __future__ import annotations
@@ -738,8 +747,9 @@ def accumulate_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
         raise ValueError(
             f"accumulate of {dt} needs element-aligned segment/pool "
             f"bytes (seg={seg}, pool_bytes={arena_shape[1]})")
-    if fetch:
-        impl = "ref"        # fused fetch rides the vectorized ref path
+    if fetch and impl == "pallas":
+        raise ValueError("fused fetch-accumulate has no Pallas kernel; "
+                         "it rides the vectorized ref path")
     key = ("accumulate", impl, arena_shape, kb, seg, flat_len, op,
            str(dt), fetch, ordered, donate)
 

@@ -13,7 +13,7 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 
 from repro.kernels.ops import (make_rdma_put, make_ring_all_gather,
                                make_ring_reduce_scatter)

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 
 from repro.core import (team_all_gather, team_all_to_all, team_barrier,
                         team_broadcast, team_pmax, team_psum,
@@ -44,7 +44,7 @@ def put_body(arena_row, v):
     return shmem_put(arena_row, v, 128, ring, "unit")
 
 
-f = jax.jit(shard_map(put_body, mesh=mesh,
+f = jax.jit(jax.shard_map(put_body, mesh=mesh,
                           in_specs=(P("unit", None), P("unit", None)),
                           out_specs=P("unit", None)))
 arena2 = f(arena, vals)
@@ -62,7 +62,7 @@ def get_body(arena_row):
     return shmem_get(arena_row, 128, 16, rev, "unit", (4,), jnp.float32)
 
 
-g = jax.jit(shard_map(get_body, mesh=mesh, in_specs=P("unit", None),
+g = jax.jit(jax.shard_map(get_body, mesh=mesh, in_specs=P("unit", None),
                           out_specs=P("unit")))
 fetched = np.asarray(g(arena2)).reshape(N, 4)
 check("shmem_get_ring", np.allclose(fetched, np.roll(np.asarray(
@@ -77,7 +77,7 @@ def dyn_body(arena_row, src):
 
 
 srcs = jnp.array([[3]] * N, dtype=jnp.int32)   # everyone reads unit 3
-d = jax.jit(shard_map(dyn_body, mesh=mesh,
+d = jax.jit(jax.shard_map(dyn_body, mesh=mesh,
                           in_specs=(P("unit", None), P("unit", None)),
                           out_specs=P("unit"), check_vma=False))
 out = np.asarray(d(arena2, srcs)).reshape(N, 4)
@@ -92,7 +92,7 @@ def halo_body(arena_row, v):
                                "unit", N, wrap=False)
 
 
-h = jax.jit(shard_map(halo_body, mesh=mesh,
+h = jax.jit(jax.shard_map(halo_body, mesh=mesh,
                           in_specs=(P("unit", None), P("unit", None)),
                           out_specs=P("unit", None)))
 arena3 = np.asarray(h(jnp.zeros((N, pool_bytes), jnp.uint8), vals))
@@ -119,7 +119,7 @@ def coll_body(xi):
     return s, m, b, ag, t.reshape(1)
 
 
-c = jax.jit(shard_map(coll_body, mesh=mesh, in_specs=P("unit"),
+c = jax.jit(jax.shard_map(coll_body, mesh=mesh, in_specs=P("unit"),
                           out_specs=(P("unit"),) * 5, check_vma=False))
 s, m, b, ag, t = c(x)
 check("team_psum", np.allclose(np.asarray(s), [6] * 4 + [22] * 4))
@@ -138,7 +138,7 @@ def rs_body(xi):
 
 
 xs = jnp.tile(jnp.arange(4, dtype=jnp.float32)[None], (N, 1))
-rs = jax.jit(shard_map(rs_body, mesh=mesh, in_specs=P("unit", None),
+rs = jax.jit(jax.shard_map(rs_body, mesh=mesh, in_specs=P("unit", None),
                            out_specs=P("unit"), check_vma=False))
 out = np.asarray(rs(xs)).reshape(-1)
 check("team_reduce_scatter", np.allclose(out, [0, 4, 8, 12] * 2))
@@ -151,7 +151,7 @@ def a2a_body(xi):
 
 
 xs = jnp.arange(N * 4, dtype=jnp.float32).reshape(N, 4)
-a2a = jax.jit(shard_map(a2a_body, mesh=mesh, in_specs=P("unit", None),
+a2a = jax.jit(jax.shard_map(a2a_body, mesh=mesh, in_specs=P("unit", None),
                             out_specs=P("unit", None), check_vma=False))
 out = np.asarray(a2a(xs)).reshape(N, 4)
 blk = np.asarray(xs).reshape(2, 4, 4)
@@ -193,7 +193,7 @@ def comp_body(g):
     return red[None], resid[None]
 
 
-cf = jax.jit(shard_map(comp_body, mesh=mesh,
+cf = jax.jit(jax.shard_map(comp_body, mesh=mesh,
                            in_specs=P("unit", None),
                            out_specs=(P("unit", None), P("unit", None)),
                            check_vma=False))

@@ -4,7 +4,8 @@ coalesced flush, handle state machine, shm fast path, dispatch counts."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (DART_TEAM_ALL, DartConfig, Locality,
                         classify_locality, dart_exit, dart_flush, dart_get,

@@ -3,7 +3,8 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (ALIGNMENT, BlockAllocator, OutOfGlobalMemory,
                         SymmetricHeap, align_up, from_bytes, nbytes_of,
@@ -173,7 +174,7 @@ def test_bytes_roundtrip(dtype, shape):
 
 
 @given(st.integers(1, 64), st.sampled_from(["float32", "int32", "bfloat16"]))
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 def test_bytes_roundtrip_property(n, dtype):
     x = jnp.arange(n).astype(dtype)
     assert np.array_equal(np.asarray(from_bytes(to_bytes(x), (n,), dtype)),

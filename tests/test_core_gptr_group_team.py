@@ -1,7 +1,8 @@
 """Unit + property tests for gptr/group/team (paper §III, §IV.B.1/2/4)."""
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (DART_GPTR_NULL, GlobalPtr, DartGroup, FreeListTeamList,
                         Team, TeamList, TeamListFullError, TeamPartition,

@@ -6,7 +6,8 @@ and the waitall lane-error fix."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (DART_TEAM_ALL, DartConfig, dart_exit, dart_flush,
                         dart_get_blocking, dart_get_nb, dart_init,
@@ -234,6 +235,32 @@ def test_pallas_gather_matches_ref(ctx):
         for i, (n, h) in enumerate(zip(sizes, hs)):
             np.testing.assert_array_equal(
                 np.asarray(h.value()), np.arange(n, dtype=np.uint8) + 5 * i)
+
+
+def test_pallas_window_fallback_is_counted():
+    """A run that fails the Pallas window precondition is served by ref
+    and counted as a fallback — never a silent kernel switch."""
+    c = dart_init(n_units=2, config=DartConfig(
+        non_collective_pool_bytes=1024, team_pool_bytes=1024))
+    try:
+        c.engine.impl = "pallas"
+        g = dart_memalloc(c, 1024, unit=1)
+        dart_put_blocking(c, g, np.arange(8, dtype=np.uint8))
+        stats = c.engine.dispatch_stats()
+        assert stats["dispatches"] == {"ref": 0, "pallas": 1}
+        assert stats["impl_fallbacks"] == 0
+        # 3 B at the pool's last bytes: the 16 B padded window overruns
+        tail = g + (1024 - 3)
+        dart_put_blocking(c, tail, np.array([7, 8, 9], np.uint8))
+        stats = c.engine.dispatch_stats()
+        assert stats["dispatches"] == {"ref": 1, "pallas": 1}
+        assert stats["impl_fallbacks"] == 1
+        np.testing.assert_array_equal(
+            np.asarray(dart_get_blocking(c, tail, (3,), jnp.uint8)),
+            [7, 8, 9])
+        assert c.engine.dispatch_stats()["impl_fallbacks"] == 2
+    finally:
+        dart_exit(c)
 
 
 # ------------------------------------- mixed get run: one counted dispatch -

@@ -5,7 +5,8 @@ coalescing, typed collectives, local zero-copy view, epochs."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (DART_TEAM_ALL, DartConfig, GlobalArray, GlobalRef,
                         OutOfGlobalMemory, dart_exit, dart_init,

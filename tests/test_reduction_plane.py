@@ -22,7 +22,8 @@ import random
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (DART_TEAM_ALL, DartConfig, dart_accumulate,
                         dart_accumulate_blocking, dart_allreduce,
@@ -165,7 +166,7 @@ def test_differential_sequences_vs_blocking_oracle(op, dtype, engine_impl):
        st.booleans())
 @settings(max_examples=25, deadline=None)
 def test_interleaved_ops_byte_identical(op_specs, use_pallas):
-    """Property (collected via the _hypothesis_compat shim): any
+    """Property (hypothesis): any
     interleaving of mixed-op accumulates, puts, and reads matches the
     sequential oracle — mixed-op overlap splits runs, reads flush
     their lane first."""

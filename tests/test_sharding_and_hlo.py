@@ -55,7 +55,8 @@ def test_sized_spec_fsdp_embed():
     assert spec == P(None, "model")
 
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 LOGICAL = [None, "embed", "vocab", "q_heads", "kv_heads", "mlp",
            "experts", "batch", "seq", "layers"]

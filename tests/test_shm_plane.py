@@ -25,7 +25,8 @@ import numpy as np
 import pytest
 
 from repro.core import (DART_TEAM_ALL, DartConfig, DartError, DartGroup,
-                        ShmBoundsError, UnitFailedError, dart_exit,
+                        Locality, ShmBoundsError, UnitFailedError,
+                        classify_locality, dart_exit,
                         dart_get_nb, dart_init, dart_put,
                         dart_put_blocking, dart_shm_view,
                         dart_team_create, dart_team_destroy,
@@ -87,6 +88,39 @@ def test_mixed_visibility_cache_is_per_pool(ctx):
     dart_team_destroy(ctx, teamid)
     assert pool_bad not in ctx._shm_cache
     assert shm_supported(ctx, pool_bad) is False       # pool is gone
+
+
+class _TpuArena:
+    """An arena stand-in that lives on a TPU: any host probe of it is a
+    bug, because its buffer pointer is a device address."""
+
+    def devices(self):
+        class _Dev:
+            platform = "tpu"
+        return {_Dev()}
+
+    def __dlpack__(self, *a, **k):
+        raise AssertionError("dlpack probe of a device arena")
+
+    def unsafe_buffer_pointer(self):
+        raise AssertionError("pointer probe of a device arena")
+
+
+def test_device_arena_is_never_host_visible(ctx):
+    """An arena on an accelerator probes as (False, False) before any
+    dlpack or pointer probe, and its pointers route to the engine."""
+    g = dart_team_memalloc_shared(ctx, DART_TEAM_ALL, 64)
+    poolid, _, _ = _lane_of(ctx, g)
+    real = ctx.state[poolid]
+    invalidate_shm_cache(ctx, poolid)
+    ctx.state[poolid] = _TpuArena()
+    try:
+        assert shm_supported(ctx, poolid) is False
+        assert shm_writable(ctx, poolid) is False
+        assert classify_locality(ctx, g) is Locality.REMOTE
+    finally:
+        ctx.state[poolid] = real
+        invalidate_shm_cache(ctx, poolid)
 
 
 def test_cache_cleared_on_exit():
