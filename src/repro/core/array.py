@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .globmem import nbytes_of
 from .gptr import GlobalPtr
 from .team import DART_TEAM_ALL
@@ -188,13 +189,19 @@ class GlobalRef:
                          seg, stride, count)
 
     def _coerce(self, value) -> jax.Array:
-        v = jnp.asarray(value, dtype=self.dtype)
-        if v.shape == self.shape:
-            return v
-        if v.ndim == 0:
-            return jnp.broadcast_to(v, self.shape)
-        if v.size == int(np.prod(self.shape, dtype=np.int64)):
-            return v.reshape(self.shape)
+        """``value`` as a device array of this ref's dtype and shape, in
+        a ``dart.coerce`` span that counts the host->device bytes of a
+        host input."""
+        with tracing.span("dart.coerce") as sp:
+            v = jnp.asarray(value, dtype=self.dtype)
+            if sp.on and not isinstance(value, jax.Array):
+                sp.add(h2d_bytes=int(v.nbytes))
+            if v.shape == self.shape:
+                return v
+            if v.ndim == 0:
+                return jnp.broadcast_to(v, self.shape)
+            if v.size == int(np.prod(self.shape, dtype=np.int64)):
+                return v.reshape(self.shape)
         raise ValueError(
             f"value of shape {v.shape} does not fit ref of shape "
             f"{self.shape}")

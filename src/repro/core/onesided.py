@@ -125,6 +125,7 @@ import numpy as np
 
 from repro.kernels import segmented_copy as _sc
 
+from . import tracing
 from .faults import (DartError, FaultPlane, FlushTimeoutError,
                      RetriesExhaustedError, TransientDispatchFault,
                      UnitFailedError)
@@ -155,6 +156,16 @@ def _to_host_bytes(value) -> np.ndarray:
     return arr
 
 
+def _stage(value) -> np.ndarray:
+    """:func:`_to_host_bytes` on the enqueue path, in a ``dart.stage``
+    span that counts the device->host bytes of a ``jax.Array`` input."""
+    with tracing.span("dart.stage") as sp:
+        payload = _to_host_bytes(value)
+        if sp.on and isinstance(value, jax.Array):
+            sp.add(d2h_bytes=int(payload.size))
+    return payload
+
+
 def _host_decode(raw: np.ndarray, shape: Tuple[int, ...], dtype
                  ) -> np.ndarray:
     """Inverse of :func:`_to_host_bytes` on a host byte window."""
@@ -183,14 +194,15 @@ def _block_ready(arrays) -> None:
     tolerance as :func:`_arr_done` — a batched
     ``jax.block_until_ready(list)`` would raise on a buffer donated
     after the caller's ``is_deleted`` filter ran."""
-    for a in arrays:
-        try:
-            if not a.is_deleted():
-                a.block_until_ready()
-        except Exception as e:  # noqa: BLE001 - narrow on message below
-            if "deleted" in str(e) or "donated" in str(e):
-                continue
-            raise
+    with tracing.span("dart.wait", arrays=len(arrays)):
+        for a in arrays:
+            try:
+                if not a.is_deleted():
+                    a.block_until_ready()
+            except Exception as e:  # noqa: BLE001 - narrow on message below
+                if "deleted" in str(e) or "donated" in str(e):
+                    continue
+                raise
 
 
 class Handle:
@@ -298,7 +310,8 @@ class _GatherBatch:
 
     def host(self) -> np.ndarray:
         if self._host is None:
-            self._host = np.asarray(self.raws)
+            with tracing.span("dart.d2h", d2h_bytes=int(self.raws.nbytes)):
+                self._host = np.asarray(self.raws)
         return self._host
 
 
@@ -323,9 +336,11 @@ class GetHandle(Handle):
     def value(self) -> jax.Array:
         self.wait()
         if self._value is None and self._batch is not None:
-            self._value = jnp.asarray(_host_decode(
-                self._batch.host()[self._batch_idx], self.shape,
-                self.dtype))
+            raw = self._batch.host()[self._batch_idx]
+            with tracing.span("dart.decode") as sp:
+                self._value = jnp.asarray(_host_decode(raw, self.shape,
+                                                       self.dtype))
+                sp.add(h2d_bytes=int(self._value.nbytes))
         if self._value is None:
             raise self._dropped_error()
         return self._value
@@ -836,20 +851,21 @@ class CommEngine:
         ``count > 1`` the payload splits into ``count`` equal segments
         landing ``stride`` bytes apart (a strided run — ONE descriptor,
         ONE dispatch share, never one op per segment)."""
-        poolid, row, off = deref(heap, teams_by_slot, gptr)
-        payload = _to_host_bytes(value)
-        stride, count = self._check_geom(
-            "put", heap, poolid, off, int(payload.size), stride, count)
-        h = Handle((), engine=self)
-        h.poolid = poolid
-        h.row = row
-        with self.lock:
-            self._precheck_enqueue(poolid, row, gptr.unitid)
-            self._pending.append(_PendingPut(poolid, row, off, payload,
-                                             h, time.monotonic(),
-                                             stride=stride, count=count,
-                                             unit=gptr.unitid))
-            self.ops_enqueued += 1
+        with tracing.span("dart.enqueue"):
+            poolid, row, off = deref(heap, teams_by_slot, gptr)
+            payload = _stage(value)
+            stride, count = self._check_geom(
+                "put", heap, poolid, off, int(payload.size), stride, count)
+            h = Handle((), engine=self)
+            h.poolid = poolid
+            h.row = row
+            with self.lock:
+                self._precheck_enqueue(poolid, row, gptr.unitid)
+                self._pending.append(_PendingPut(poolid, row, off, payload,
+                                                 h, time.monotonic(),
+                                                 stride=stride, count=count,
+                                                 unit=gptr.unitid))
+                self.ops_enqueued += 1
         self._notify_enqueue()
         return h
 
@@ -860,20 +876,21 @@ class CommEngine:
         ``count > 1`` the bytes are gathered from ``count`` equal
         segments ``stride`` bytes apart and returned densely packed in
         the requested shape."""
-        poolid, row, off = deref(heap, teams_by_slot, gptr)
-        n = nbytes_of(shape, dtype)
-        stride, count = self._check_geom(
-            "get", heap, poolid, off, n, stride, count)
-        h = GetHandle(shape, dtype, engine=self)
-        h.poolid = poolid
-        h.row = row
-        with self.lock:
-            self._precheck_enqueue(poolid, row, gptr.unitid)
-            self._pending.append(_PendingGet(poolid, row, off, n, h,
-                                             time.monotonic(),
-                                             stride=stride, count=count,
-                                             unit=gptr.unitid))
-            self.ops_enqueued += 1
+        with tracing.span("dart.enqueue"):
+            poolid, row, off = deref(heap, teams_by_slot, gptr)
+            n = nbytes_of(shape, dtype)
+            stride, count = self._check_geom(
+                "get", heap, poolid, off, n, stride, count)
+            h = GetHandle(shape, dtype, engine=self)
+            h.poolid = poolid
+            h.row = row
+            with self.lock:
+                self._precheck_enqueue(poolid, row, gptr.unitid)
+                self._pending.append(_PendingGet(poolid, row, off, n, h,
+                                                 time.monotonic(),
+                                                 stride=stride, count=count,
+                                                 unit=gptr.unitid))
+                self.ops_enqueued += 1
         self._notify_enqueue()
         return h
 
@@ -894,12 +911,15 @@ class CommEngine:
             raise ValueError(f"unknown reduction op {op!r} "
                              f"(supported: {sorted(_sc.REDUCE_OPS)})")
         poolid, row, off = deref(heap, teams_by_slot, gptr)
-        arr = np.asarray(value)
-        canon = jax.dtypes.canonicalize_dtype(arr.dtype)
-        if arr.dtype != canon:
-            arr = arr.astype(canon)
+        with tracing.span("dart.stage") as sp:
+            arr = np.asarray(value)
+            canon = jax.dtypes.canonicalize_dtype(arr.dtype)
+            if arr.dtype != canon:
+                arr = arr.astype(canon)
+            payload = _to_host_bytes(arr)     # same staging rule as puts
+            if sp.on and isinstance(value, jax.Array):
+                sp.add(d2h_bytes=int(payload.size))
         dt = jnp.dtype(canon)
-        payload = _to_host_bytes(arr)     # same staging rule as puts
         pool_bytes = heap.pools[poolid].pool_bytes
         if off % dt.itemsize or pool_bytes % dt.itemsize:
             raise ValueError(
@@ -924,19 +944,20 @@ class CommEngine:
         flush — even overlapping ones (the ops commute), while
         mixed-op or accumulate-vs-put overlap splits the run in queue
         order (last-writer-wins preserved run-by-run)."""
-        poolid, row, off, _, payload, dt, stride, count = self._stage_acc(
-            heap, teams_by_slot, gptr, value, op, stride, count)
-        h = Handle((), engine=self)
-        h.poolid = poolid
-        h.row = row
-        with self.lock:
-            self._precheck_enqueue(poolid, row, gptr.unitid)
-            self._pending.append(_PendingAcc(poolid, row, off, payload,
-                                             op, str(dt), False, h,
-                                             time.monotonic(),
-                                             stride=stride, count=count,
-                                             unit=gptr.unitid))
-            self.ops_enqueued += 1
+        with tracing.span("dart.enqueue"):
+            poolid, row, off, _, payload, dt, stride, count = self._stage_acc(
+                heap, teams_by_slot, gptr, value, op, stride, count)
+            h = Handle((), engine=self)
+            h.poolid = poolid
+            h.row = row
+            with self.lock:
+                self._precheck_enqueue(poolid, row, gptr.unitid)
+                self._pending.append(_PendingAcc(poolid, row, off, payload,
+                                                 op, str(dt), False, h,
+                                                 time.monotonic(),
+                                                 stride=stride, count=count,
+                                                 unit=gptr.unitid))
+                self.ops_enqueued += 1
         self._notify_enqueue()
         return h
 
@@ -948,19 +969,21 @@ class CommEngine:
         *before* this op applied.  Byte-disjoint same-op fetches share
         one fused dispatch; overlap splits the run so every fetched
         value matches the sequential order."""
-        poolid, row, off, arr, payload, dt, stride, count = self._stage_acc(
-            heap, teams_by_slot, gptr, value, op, stride, count)
-        h = GetHandle(arr.shape, dt, engine=self)
-        h.poolid = poolid
-        h.row = row
-        with self.lock:
-            self._precheck_enqueue(poolid, row, gptr.unitid)
-            self._pending.append(_PendingAcc(poolid, row, off, payload,
-                                             op, str(dt), True, h,
-                                             time.monotonic(),
-                                             stride=stride, count=count,
-                                             unit=gptr.unitid))
-            self.ops_enqueued += 1
+        with tracing.span("dart.enqueue"):
+            (poolid, row, off, arr, payload, dt, stride,
+             count) = self._stage_acc(heap, teams_by_slot, gptr, value,
+                                      op, stride, count)
+            h = GetHandle(arr.shape, dt, engine=self)
+            h.poolid = poolid
+            h.row = row
+            with self.lock:
+                self._precheck_enqueue(poolid, row, gptr.unitid)
+                self._pending.append(_PendingAcc(poolid, row, off, payload,
+                                                 op, str(dt), True, h,
+                                                 time.monotonic(),
+                                                 stride=stride, count=count,
+                                                 unit=gptr.unitid))
+                self.ops_enqueued += 1
         self._notify_enqueue()
         return h
 
@@ -1037,69 +1060,82 @@ class CommEngine:
                 rest = [op for op in self._pending if not _sel(op)]
             if not todo:
                 return self._holder.state
-            state = copy_state(self._holder.state)
-            failed_now: Set[Tuple[int, int]] = set()
-            for run, disjoint in _coalesced_runs(todo):
-                pid = run[0].poolid
-                if failed_now:
-                    # program order on a lane that just failed: fail
-                    # the lane's later ops instead of dispatching them
-                    # past the hole the dropped run left
-                    live = []
-                    for op in run:
-                        lane = (op.poolid, op.row)
-                        if lane in failed_now:
-                            op.handle._fail(self.failed_lanes[lane])
-                        else:
-                            live.append(op)
-                    if not live:
-                        continue
-                    run = live
-                try:
-                    if isinstance(run[0], _PendingPut):
-                        cell = {"arena": state[pid]}
-
-                        def _put(cell=cell, run=run, disjoint=disjoint):
-                            cell["arena"] = self._dispatch_put_run(
-                                cell["arena"], run, disjoint)
-                        try:
-                            self._guarded("put", run, _put,
-                                          retryable_post=True)
-                        finally:
-                            state[pid] = cell["arena"]
-                        for op in run:
-                            op.handle._resolve((state[pid],))
-                    elif isinstance(run[0], _PendingAcc):
-                        cell = {"arena": state[pid]}
-
-                        def _acc(cell=cell, run=run, disjoint=disjoint):
-                            cell["arena"] = self._dispatch_acc_run(
-                                cell["arena"], run, disjoint)
-                        try:
-                            # at-most-once: a post-dispatch fault on an
-                            # RMW run must never re-issue
-                            self._guarded("gacc" if run[0].fetch
-                                          else "acc", run, _acc,
-                                          retryable_post=False)
-                        finally:
-                            state[pid] = cell["arena"]
-                    else:
-                        def _get(run=run, arena=state[pid]):
-                            self._dispatch_get_run(arena, run)
-                        self._guarded("get", run, _get,
-                                      retryable_post=True)
-                except DartError as e:
-                    self.failed_runs += 1
-                    lanes = {(op.poolid, op.row) for op in run}
-                    for op in run:
-                        op.handle._fail(e)
-                    for lane in lanes:
-                        self.failed_lanes[lane] = e
-                    failed_now |= lanes
+            with tracing.span("dart.flush", epoch=self.epoch,
+                              ops=len(todo)) as sp:
+                dispatched = self.dispatch_count
+                state = self._dispatch_epoch(todo)
+                sp.add(runs=self.dispatch_count - dispatched)
             self._pending = rest
             self._holder.state = state
             self.epoch += 1
             return state
+
+    def _dispatch_epoch(self, todo: List) -> HeapState:
+        """Under the engine lock: dispatch ``todo``'s coalesced runs in
+        program order on a copy of the holder's state and return it
+        (failure isolation as :meth:`flush` describes)."""
+        state = copy_state(self._holder.state)
+        failed_now: Set[Tuple[int, int]] = set()
+        with tracing.span("dart.coalesce"):
+            runs = _coalesced_runs(todo)
+        for run, disjoint in runs:
+            pid = run[0].poolid
+            if failed_now:
+                # program order on a lane that just failed: fail
+                # the lane's later ops instead of dispatching them
+                # past the hole the dropped run left
+                live = []
+                for op in run:
+                    lane = (op.poolid, op.row)
+                    if lane in failed_now:
+                        op.handle._fail(self.failed_lanes[lane])
+                    else:
+                        live.append(op)
+                if not live:
+                    continue
+                run = live
+            try:
+                if isinstance(run[0], _PendingPut):
+                    cell = {"arena": state[pid]}
+
+                    def _put(cell=cell, run=run, disjoint=disjoint):
+                        cell["arena"] = self._dispatch_put_run(
+                            cell["arena"], run, disjoint)
+                    try:
+                        self._guarded("put", run, _put,
+                                      retryable_post=True)
+                    finally:
+                        state[pid] = cell["arena"]
+                    for op in run:
+                        op.handle._resolve((state[pid],))
+                elif isinstance(run[0], _PendingAcc):
+                    cell = {"arena": state[pid]}
+
+                    def _acc(cell=cell, run=run, disjoint=disjoint):
+                        cell["arena"] = self._dispatch_acc_run(
+                            cell["arena"], run, disjoint)
+                    try:
+                        # at-most-once: a post-dispatch fault on an
+                        # RMW run must never re-issue
+                        self._guarded("gacc" if run[0].fetch
+                                      else "acc", run, _acc,
+                                      retryable_post=False)
+                    finally:
+                        state[pid] = cell["arena"]
+                else:
+                    def _get(run=run, arena=state[pid]):
+                        self._dispatch_get_run(arena, run)
+                    self._guarded("get", run, _get,
+                                  retryable_post=True)
+            except DartError as e:
+                self.failed_runs += 1
+                lanes = {(op.poolid, op.row) for op in run}
+                for op in run:
+                    op.handle._fail(e)
+                for lane in lanes:
+                    self.failed_lanes[lane] = e
+                failed_now |= lanes
+        return state
 
     def _guarded(self, kind: str, run: Sequence, attempt: Callable[[], None],
                  retryable_post: bool) -> None:
@@ -1212,20 +1248,25 @@ class CommEngine:
         self.dispatch_count += 1
         if len(run) > 1:
             self.ops_coalesced += len(run)
-        desc, flat, seg = _sc.pack_descriptors(
-            [op.row for op in run], [op.off for op in run],
-            [int(op.payload.size) // op.count for op in run],
-            [op.payload for op in run],
-            strides=[op.stride for op in run],
-            counts=[op.count for op in run])
-        impl = self._pick_impl(desc, seg, int(arena.shape[1]))
-        sseg, cb = (_sc.strided_buckets(desc, seg)
-                    if impl == "pallas" else (None, None))
-        fn, hit = _sc.scatter_plan(
-            arena.shape, desc.shape[0], seg, flat.shape[0],
-            ordered=not disjoint, impl=impl, sseg=sseg, cb=cb)
-        self._note_plan(hit)
-        return fn(arena, desc, flat)
+        with tracing.span("dart.pack"):
+            desc, flat, seg = _sc.pack_descriptors(
+                [op.row for op in run], [op.off for op in run],
+                [int(op.payload.size) // op.count for op in run],
+                [op.payload for op in run],
+                strides=[op.stride for op in run],
+                counts=[op.count for op in run])
+        with tracing.span("dart.launch") as sp:
+            impl = self._pick_impl(desc, seg, int(arena.shape[1]))
+            sseg, cb = (_sc.strided_buckets(desc, seg)
+                        if impl == "pallas" else (None, None))
+            fn, hit = _sc.scatter_plan(
+                arena.shape, desc.shape[0], seg, flat.shape[0],
+                ordered=not disjoint, impl=impl, sseg=sseg, cb=cb)
+            self._note_plan(hit)
+            arena = fn(arena, desc, flat)
+            if sp.on:
+                sp.add(**_launch_counts(desc, flat, seg, hit))
+        return arena
 
     def _dispatch_acc_run(self, arena: jax.Array,
                           run: Sequence["_PendingAcc"],
@@ -1242,31 +1283,37 @@ class CommEngine:
         if len(run) > 1:
             self.ops_coalesced += len(run)
         first = run[0]
-        desc, flat, seg = _sc.pack_acc_descriptors(
-            [op.row for op in run], [op.off for op in run],
-            [int(op.payload.size) // op.count for op in run],
-            [op.payload for op in run], first.op, first.dtype,
-            strides=[op.stride for op in run],
-            counts=[op.count for op in run])
-        # strided RMW and fused fetches ride the ref kernels only: the
-        # Pallas accumulate keeps its exact kb*seg identity-slot layout
-        # (contiguous runs) and returns no pre-update windows
-        impl = self._pick_impl(
-            desc, seg, int(arena.shape[1]),
-            has_pallas=not first.fetch and all(op.count == 1 for op in run))
-        fn, hit = _sc.accumulate_plan(
-            arena.shape, desc.shape[0], seg, flat.shape[0],
-            op=first.op, dtype=first.dtype, fetch=first.fetch,
-            ordered=not disjoint, impl=impl)
-        self._note_plan(hit)
+        with tracing.span("dart.pack"):
+            desc, flat, seg = _sc.pack_acc_descriptors(
+                [op.row for op in run], [op.off for op in run],
+                [int(op.payload.size) // op.count for op in run],
+                [op.payload for op in run], first.op, first.dtype,
+                strides=[op.stride for op in run],
+                counts=[op.count for op in run])
+        with tracing.span("dart.launch") as sp:
+            # strided RMW and fused fetches ride the ref kernels only:
+            # the Pallas accumulate keeps its exact kb*seg identity-slot
+            # layout (contiguous runs) and returns no pre-update windows
+            impl = self._pick_impl(
+                desc, seg, int(arena.shape[1]),
+                has_pallas=not first.fetch
+                and all(op.count == 1 for op in run))
+            fn, hit = _sc.accumulate_plan(
+                arena.shape, desc.shape[0], seg, flat.shape[0],
+                op=first.op, dtype=first.dtype, fetch=first.fetch,
+                ordered=not disjoint, impl=impl)
+            self._note_plan(hit)
+            out = fn(arena, desc, flat)
+            if sp.on:
+                sp.add(**_launch_counts(desc, flat, seg, hit))
         if first.fetch:
-            arena, old = fn(arena, desc, flat)
+            arena, old = out
             batch = _GatherBatch(old)
             self._record_read_fence(first.poolid, old)
             for i, op in enumerate(run):
                 op.handle._resolve_gather(batch, i)
         else:
-            arena = fn(arena, desc, flat)
+            arena = out
             for op in run:
                 op.handle._resolve((arena,))
         return arena
@@ -1282,19 +1329,23 @@ class CommEngine:
         self.dispatch_count += 1
         if len(run) > 1:
             self.ops_coalesced += len(run)
-        desc, _, seg = _sc.pack_descriptors(
-            [op.row for op in run], [op.off for op in run],
-            [op.nbytes // op.count for op in run],
-            strides=[op.stride for op in run],
-            counts=[op.count for op in run])
-        impl = self._pick_impl(desc, seg, int(arena.shape[1]))
-        sseg, cb = (_sc.strided_buckets(desc, seg)
-                    if impl == "pallas" else (None, None))
-        fn, hit = _sc.gather_plan(
-            arena.shape, desc.shape[0], seg, impl=impl, sseg=sseg,
-            cb=cb)
-        self._note_plan(hit)
-        batch = _GatherBatch(fn(arena, desc))
+        with tracing.span("dart.pack"):
+            desc, _, seg = _sc.pack_descriptors(
+                [op.row for op in run], [op.off for op in run],
+                [op.nbytes // op.count for op in run],
+                strides=[op.stride for op in run],
+                counts=[op.count for op in run])
+        with tracing.span("dart.launch") as sp:
+            impl = self._pick_impl(desc, seg, int(arena.shape[1]))
+            sseg, cb = (_sc.strided_buckets(desc, seg)
+                        if impl == "pallas" else (None, None))
+            fn, hit = _sc.gather_plan(
+                arena.shape, desc.shape[0], seg, impl=impl, sseg=sseg,
+                cb=cb)
+            self._note_plan(hit)
+            batch = _GatherBatch(fn(arena, desc))
+            if sp.on:
+                sp.add(**_launch_counts(desc, None, seg, hit))
         self._record_read_fence(run[0].poolid, batch.raws)
         for i, op in enumerate(run):
             op.handle._resolve_gather(batch, i)
@@ -1335,6 +1386,21 @@ def _op_nbytes(op) -> int:
     if isinstance(op, _PendingPut) or isinstance(op, _PendingAcc):
         return int(op.payload.size)
     return op.nbytes
+
+
+def _launch_counts(desc: np.ndarray, flat: Optional[np.ndarray],
+                   seg: int, hit: bool) -> Dict[str, int]:
+    """The ``dart.launch`` counters of one run's dispatch: the bytes its
+    ops asked for (``len x count`` summed over the descriptors; padding
+    rows are zero), the bucket lanes the plan moves (``kb x seg``), the
+    bytes staged host->device (descriptors + flat payload), and whether
+    the plan cache missed."""
+    asked = desc[:, _sc.LEN].astype(np.int64) * desc[:, _sc.COUNT]
+    return {"asked_bytes": int(asked.sum()),
+            "lane_bytes": int(desc.shape[0]) * seg,
+            "h2d_bytes": int(desc.nbytes) + (0 if flat is None
+                                             else int(flat.nbytes)),
+            "miss": int(not hit)}
 
 
 def _op_span(op) -> int:

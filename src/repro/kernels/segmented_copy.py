@@ -650,6 +650,16 @@ _BUILD_COUNT = [0]      # process-total plan builds (≈ XLA compiles)
 _PLAN_LOCK = threading.Lock()
 
 
+def _named(name: str, fn: Callable, **static) -> Callable:
+    """``fn`` with its static arguments bound, under ``name``: the plan
+    lowers as ``module @jit_<name>``, so a trace or an HLO dump says
+    which plan ran (a bare ``functools.partial`` lowers as
+    ``jit__unknown``)."""
+    plan = functools.partial(fn, **static)
+    plan.__name__ = plan.__qualname__ = name
+    return plan
+
+
 def cached_plan(key: Tuple, build: Callable[[], Callable]
                 ) -> Tuple[Callable, bool]:
     """Process-wide executable cache (the DispatchPlan layer): returns
@@ -701,12 +711,13 @@ def scatter_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
 
     def build():
         if impl == "pallas":
-            fn = functools.partial(_pallas_scatter, seg=seg, sseg=sseg,
-                                   cb=cb)
+            fn = _named("dart_scatter_pallas", _pallas_scatter, seg=seg,
+                        sseg=sseg, cb=cb)
+        elif ordered:
+            fn = _named("dart_scatter_ordered", _ref_scatter_ordered,
+                        seg=seg)
         else:
-            fn = functools.partial(
-                _ref_scatter_ordered if ordered else _ref_scatter_vec,
-                seg=seg)
+            fn = _named("dart_scatter_vec", _ref_scatter_vec, seg=seg)
         return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
     return cached_plan(key, build)
@@ -755,14 +766,15 @@ def accumulate_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
 
     def build():
         if impl == "pallas":
-            fn = functools.partial(_pallas_accumulate, seg=seg, op=op,
-                                   dt=dt)
+            fn = _named("dart_acc_pallas", _pallas_accumulate, seg=seg,
+                        op=op, dt=dt)
         elif ordered and not fetch:
-            fn = functools.partial(_ref_accumulate_ordered, seg=seg,
-                                   op=op, dt=dt)
+            fn = _named("dart_acc_ordered", _ref_accumulate_ordered,
+                        seg=seg, op=op, dt=dt)
         else:
-            fn = functools.partial(_ref_accumulate_vec, seg=seg, op=op,
-                                   dt=dt, fetch=fetch)
+            fn = _named("dart_acc_fetch" if fetch else "dart_acc_vec",
+                        _ref_accumulate_vec, seg=seg, op=op, dt=dt,
+                        fetch=fetch)
         return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
     return cached_plan(key, build)
@@ -783,8 +795,8 @@ def gather_plan(arena_shape: Tuple[int, int], kb: int, seg: int, *,
 
     def build():
         if impl == "pallas":
-            return jax.jit(functools.partial(_pallas_gather, seg=seg,
-                                             sseg=sseg, cb=cb))
-        return jax.jit(functools.partial(_ref_gather, seg=seg))
+            return jax.jit(_named("dart_gather_pallas", _pallas_gather,
+                                  seg=seg, sseg=sseg, cb=cb))
+        return jax.jit(_named("dart_gather", _ref_gather, seg=seg))
 
     return cached_plan(key, build)

@@ -3,6 +3,7 @@ plan-cache retrace behavior, bucketed/padded dispatch equivalence,
 the Pallas segmented-copy fast path, collectives donation semantics,
 and the waitall lane-error fix."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -397,3 +398,46 @@ def test_collective_sizes_share_bucketed_plans(ctx):
         vals, _ = rt.dart_gather_typed(ctx, g, (n,), jnp.float32)
         assert vals.shape == (4, n)
     assert ctx.engine.compile_count == c0
+
+
+# ------------------------------------------------------------ plan names --
+
+_ARENA = (4, 256)
+_KB, _SEG = 4, 16
+_PLANS = {
+    "dart_scatter_vec": lambda impl: sc.scatter_plan(
+        _ARENA, _KB, _SEG, 80, ordered=False, impl=impl, donate=False),
+    "dart_scatter_ordered": lambda impl: sc.scatter_plan(
+        _ARENA, _KB, _SEG, 80, ordered=True, impl=impl, donate=False),
+    "dart_gather": lambda impl: sc.gather_plan(_ARENA, _KB, _SEG, impl=impl),
+    "dart_acc_vec": lambda impl: sc.accumulate_plan(
+        _ARENA, _KB, _SEG, 64, op="sum", dtype=jnp.float32, fetch=False,
+        impl=impl, donate=False),
+    "dart_acc_ordered": lambda impl: sc.accumulate_plan(
+        _ARENA, _KB, _SEG, 64, op="sum", dtype=jnp.float32, fetch=False,
+        ordered=True, impl=impl, donate=False),
+    "dart_acc_fetch": lambda impl: sc.accumulate_plan(
+        _ARENA, _KB, _SEG, 64, op="sum", dtype=jnp.float32, fetch=True,
+        impl=impl, donate=False),
+}
+_PALLAS_PLANS = {"dart_scatter_pallas": "dart_scatter_vec",
+                 "dart_gather_pallas": "dart_gather",
+                 "dart_acc_pallas": "dart_acc_vec"}
+
+
+@pytest.mark.parametrize("name,impl", [(n, "ref") for n in _PLANS]
+                         + [(n, "pallas") for n in _PALLAS_PLANS])
+def test_every_plan_lowers_under_its_own_name(name, impl):
+    """A trace names the module a dispatch ran (``jit_dart_*``), so
+    each plan lowers as its own module, never ``jit__unknown``."""
+    fn, _ = _PLANS[_PALLAS_PLANS.get(name, name)](impl)
+    args = [jax.ShapeDtypeStruct(_ARENA, jnp.uint8),
+            jax.ShapeDtypeStruct(
+                (_KB, sc.ACC_DESC_COLS if "acc" in name else sc.DESC_COLS),
+                jnp.int32)]
+    if "gather" not in name:
+        flat = 64 if "acc" in name else 80
+        args.append(jax.ShapeDtypeStruct((flat,), jnp.uint8))
+    text = fn.lower(*args).as_text()
+    assert f"module @jit_{name} " in text
+    assert "jit__unknown" not in text
