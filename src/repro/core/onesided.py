@@ -600,6 +600,9 @@ class CommEngine:
         self.plan_cache_hits = 0
         #: one-sided run dispatches by the kernel impl that served them
         self.impl_dispatches: Dict[str, int] = {"ref": 0, "pallas": 0}
+        #: the ref dispatches among them that moved (1, seg) windows of
+        #: the 2-D arena (:func:`_window_path`), not flat byte lanes
+        self.window_dispatches = 0
         #: runs of a 'pallas' engine served by 'ref' instead
         self.impl_fallbacks = 0
         # -- shm plane (repro.core.shm; docs/API.md "Shared-memory
@@ -801,12 +804,14 @@ class CommEngine:
 
     def dispatch_stats(self) -> Dict[str, object]:
         """Which kernels served the one-sided runs: the engine impl,
-        dispatches per impl, Pallas→ref fallbacks, and the plan cache's
-        compile/hit counts."""
+        dispatches per impl, the ref dispatches the window kernels
+        served, Pallas→ref fallbacks, and the plan cache's compile/hit
+        counts."""
         with self.lock:
             return {
                 "impl": self.impl,
                 "dispatches": dict(self.impl_dispatches),
+                "window_dispatches": self.window_dispatches,
                 "impl_fallbacks": self.impl_fallbacks,
                 "compile_count": self.compile_count,
                 "plan_cache_hits": self.plan_cache_hits,
@@ -843,6 +848,14 @@ class CommEngine:
                 self.impl_fallbacks += 1
         self.impl_dispatches[impl] += 1
         return impl
+
+    def _pick_window(self, impl: str, desc: np.ndarray, seg: int,
+                     arena: jax.Array) -> bool:
+        """Whether a ref put/get run moves windows (counted); see
+        :func:`_window_path`."""
+        window = impl == "ref" and _window_path(desc, seg, arena)
+        self.window_dispatches += window
+        return window
 
     # -- enqueue (initiation) -------------------------------------------
     def put(self, heap: SymmetricHeap, teams_by_slot, gptr: GlobalPtr,
@@ -1257,15 +1270,17 @@ class CommEngine:
                 counts=[op.count for op in run])
         with tracing.span("dart.launch") as sp:
             impl = self._pick_impl(desc, seg, int(arena.shape[1]))
+            window = self._pick_window(impl, desc, seg, arena)
             sseg, cb = (_sc.strided_buckets(desc, seg)
                         if impl == "pallas" else (None, None))
             fn, hit = _sc.scatter_plan(
                 arena.shape, desc.shape[0], seg, flat.shape[0],
-                ordered=not disjoint, impl=impl, sseg=sseg, cb=cb)
+                ordered=not disjoint, impl=impl, sseg=sseg, cb=cb,
+                window=window)
             self._note_plan(hit)
             arena = fn(arena, desc, flat)
             if sp.on:
-                sp.add(**_launch_counts(desc, flat, seg, hit))
+                sp.add(**_launch_counts(desc, flat, seg, hit, window))
         return arena
 
     def _dispatch_acc_run(self, arena: jax.Array,
@@ -1337,15 +1352,16 @@ class CommEngine:
                 counts=[op.count for op in run])
         with tracing.span("dart.launch") as sp:
             impl = self._pick_impl(desc, seg, int(arena.shape[1]))
+            window = self._pick_window(impl, desc, seg, arena)
             sseg, cb = (_sc.strided_buckets(desc, seg)
                         if impl == "pallas" else (None, None))
             fn, hit = _sc.gather_plan(
                 arena.shape, desc.shape[0], seg, impl=impl, sseg=sseg,
-                cb=cb)
+                cb=cb, window=window)
             self._note_plan(hit)
             batch = _GatherBatch(fn(arena, desc))
             if sp.on:
-                sp.add(**_launch_counts(desc, None, seg, hit))
+                sp.add(**_launch_counts(desc, None, seg, hit, window))
         self._record_read_fence(run[0].poolid, batch.raws)
         for i, op in enumerate(run):
             op.handle._resolve_gather(batch, i)
@@ -1389,18 +1405,32 @@ def _op_nbytes(op) -> int:
 
 
 def _launch_counts(desc: np.ndarray, flat: Optional[np.ndarray],
-                   seg: int, hit: bool) -> Dict[str, int]:
+                   seg: int, hit: bool, window: bool = False
+                   ) -> Dict[str, int]:
     """The ``dart.launch`` counters of one run's dispatch: the bytes its
     ops asked for (``len x count`` summed over the descriptors; padding
     rows are zero), the bucket lanes the plan moves (``kb x seg``), the
-    bytes staged host->device (descriptors + flat payload), and whether
-    the plan cache missed."""
+    bytes staged host->device (descriptors + flat payload), whether
+    the plan cache missed, and whether the window kernels served it."""
     asked = desc[:, _sc.LEN].astype(np.int64) * desc[:, _sc.COUNT]
     return {"asked_bytes": int(asked.sum()),
             "lane_bytes": int(desc.shape[0]) * seg,
             "h2d_bytes": int(desc.nbytes) + (0 if flat is None
                                              else int(flat.nbytes)),
-            "miss": int(not hit)}
+            "miss": int(not hit),
+            "window": int(window)}
+
+
+def _window_path(desc: np.ndarray, seg: int, arena: jax.Array) -> bool:
+    """True iff a put/get run can move each descriptor as one ``(1,
+    seg)`` window of the 2-D arena: every descriptor contiguous
+    (``COUNT <= 1``), the segment bucket no wider than a row, and the
+    arena held by one device.  Strided runs, wider buckets and sharded
+    arenas keep the flat lane kernels (on a sharded arena the window
+    kernels would all-gather it)."""
+    return (seg <= arena.shape[1]
+            and bool(np.all(desc[:, _sc.COUNT] <= 1))
+            and len(arena.sharding.device_set) == 1)
 
 
 def _op_span(op) -> int:

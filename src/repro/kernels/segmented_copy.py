@@ -24,8 +24,23 @@ This module removes the shape dependence:
   ``count`` segments of ``len`` bytes, ``stride`` bytes apart — so a
   matrix column or tile halo is ONE descriptor, not one per element;
   contiguous ops are the ``stride=0, count=1`` degenerate case.
-* **Flat-index addressing** — kernels address the arena as a flat byte
-  string: op *i* touches positions
+* **Window addressing** — a put or get run whose descriptors are all
+  contiguous (``count <= 1``), whose segment bucket fits a row
+  (``seg <= P``) and whose arena is held by one device moves each
+  descriptor as one ``(1, seg)`` window of the 2-D ``(R, P)`` arena
+  (:func:`_win_scatter`, :func:`_win_gather`): the window starts at
+  ``s = min(off, P - seg)``, the op's bytes sit at lanes ``[off - s,
+  off - s + len)`` of it, and a loop over the descriptors in queue
+  order reads, merges and writes each window back.  The arena is never
+  reshaped, so the donated buffer is updated in place and a dispatch
+  costs O(run), not O(arena); one kernel serves disjoint and
+  overlapping (last-writer-wins) runs.  The engine picks this path
+  from the run and the arena alone
+  (:func:`repro.core.onesided._window_path`).
+* **Flat-index addressing** (the lane path) — strided runs, runs
+  whose bucket is wider than a row, and row-sharded arenas (the
+  four-chip layout, where a window plan would all-gather the arena)
+  address the arena as a flat byte string: op *i* touches positions
   ``row*P + off + (lane//len)*stride + lane%len`` for
   ``lane < len*count`` (payloads stay dense in lane order); masked
   lanes are routed to distinct out-of-range indices and dropped
@@ -35,12 +50,16 @@ This module removes the shape dependence:
   headroom — the bounds check at initiation is the only range
   requirement.  One formula serves contiguous and strided ops alike,
   so stride/count live in the traced descriptor *data*, never the plan
-  key: a varying-stride loop performs zero recompiles.
-* **Vectorized vs ordered** — runs whose byte ranges are provably
-  disjoint (``_RunMeta`` tracks this while the run is grown) dispatch
-  as ONE vectorized segmented update (``unique_indices`` scatter);
-  only overlapping uniform runs keep the sequential ``fori_loop`` so
-  last-writer-wins program order is preserved.
+  key: a varying-stride loop performs zero recompiles.  The flat index
+  is int32, so :func:`check_flat_addressable` refuses arenas of 2**30
+  bytes or more — on the lane path only; the window path addresses
+  ``(row, off)``.  The accumulate plans and the host-plane collectives
+  address the arena flat too.
+* **Vectorized vs ordered** (lane path) — runs whose byte ranges are
+  provably disjoint (``_RunMeta`` tracks this while the run is grown)
+  dispatch as ONE vectorized segmented update (``unique_indices``
+  scatter); only overlapping uniform runs keep the sequential
+  ``fori_loop`` so last-writer-wins program order is preserved.
 * **Reduction plane** — accumulate runs (``dart_accumulate`` /
   ``dart_get_accumulate``) ride the same substrate through segmented
   read-modify-write kernels (:func:`accumulate_plan`): descriptors
@@ -144,9 +163,9 @@ def pack_descriptors(rows: Sequence[int], offs: Sequence[int],
     payloads pack densely (segment j of op i at
     ``start + j*len``); the buffer carries a trailing ``seg`` bytes of
     zero margin so a pad-to-bucket window read starting at any valid
-    ``start`` stays in range (the Pallas path relies on this; the XLA
-    path is range-safe regardless).  Returns ``(desc, flat, seg)``
-    with ``flat is None`` for gathers.
+    ``start`` stays in range (the Pallas and window kernels rely on
+    this; the lane kernels are range-safe regardless).  Returns
+    ``(desc, flat, seg)`` with ``flat is None`` for gathers.
     """
     k = len(rows)
     kb = bucket_pow2(k, K_FLOOR)
@@ -270,7 +289,7 @@ def pack_acc_descriptors(rows: Sequence[int], offs: Sequence[int],
 
 
 def check_flat_addressable(arena_shape: Tuple[int, int]) -> None:
-    """The segmented kernels address the arena as a flat int32 byte
+    """The lane kernels address the arena as a flat int32 byte
     index (``row * pool_bytes + off + lane``; OOB markers sit just
     above ``rows * pool_bytes``).  Without x64, index arithmetic stays
     int32, so arenas at or beyond 2**30 total bytes would overflow
@@ -308,7 +327,8 @@ def pallas_ok(desc: np.ndarray, seg: int, pool_bytes: int) -> bool:
 
 
 # --------------------------------------------------------------------------
-# XLA ('ref') kernels — flat-index scatter/gather, shapes fixed by buckets
+# XLA ('ref') kernels — lane (flat-index) and window scatter/gather,
+# shapes fixed by buckets
 # --------------------------------------------------------------------------
 
 
@@ -388,6 +408,62 @@ def _ref_gather(arena: jax.Array, desc: jax.Array, *, seg: int
     valid, lane = _lane_mask(desc, seg)
     idx = jnp.where(valid, _strided_dst(desc, lane, P), R * P)
     return jnp.take(arena.reshape(-1), idx, mode="fill", fill_value=0)
+
+
+def _window_at(desc: jax.Array, i, seg: int, P: int):
+    """Descriptor ``i``'s ``(1, seg)`` arena window: ``(row, s, d, n)``
+    with the clamped start ``s = min(off, P - seg)``, the op's shift
+    ``d = off - s`` inside the window and its byte count ``n`` (0 on a
+    padding row).  The bounds check at initiation keeps ``d + n <=
+    seg`` whenever ``seg <= P``."""
+    off = desc[i, OFF]
+    s = jnp.minimum(off, P - seg)
+    return desc[i, ROW], s, off - s, desc[i, LEN] * desc[i, COUNT]
+
+
+def _win_scatter(arena: jax.Array, desc: jax.Array, flat: jax.Array,
+                 *, seg: int) -> jax.Array:
+    """Contiguous segmented put on the 2-D arena: descriptors apply in
+    queue order, each one a read-modify-write of its ``(1, seg)``
+    window — payload bytes land in lanes ``[d, d + n)``, every other
+    lane writes back what it read.  One kernel serves disjoint and
+    overlapping (last-writer-wins) runs; the arena is never reshaped,
+    so the donated buffer is updated in place."""
+    P = arena.shape[1]
+    lane = jnp.arange(seg, dtype=jnp.int32)
+    pad = jnp.zeros(seg, jnp.uint8)
+
+    def body(i, a):
+        row, s, d, n = _window_at(desc, i, seg, P)
+        pay = jax.lax.dynamic_slice(flat, (desc[i, START],), (seg,))
+        shifted = jax.lax.dynamic_slice(jnp.concatenate([pad, pay]),
+                                        (seg - d,), (seg,))
+        win = jax.lax.dynamic_slice(a, (row, s), (1, seg))[0]
+        new = jnp.where((lane >= d) & (lane < d + n), shifted, win)
+        return jax.lax.dynamic_update_slice(a, new[None, :], (row, s))
+
+    return jax.lax.fori_loop(0, desc.shape[0], body, arena)
+
+
+def _win_gather(arena: jax.Array, desc: jax.Array, *, seg: int
+                ) -> jax.Array:
+    """Contiguous segmented get on the 2-D arena: each descriptor's
+    clamped ``(1, seg)`` window, shifted left by ``d`` and zeroed from
+    lane ``n`` on — the same ``(k, seg)`` pad-to-bucket rows as
+    :func:`_ref_gather`, byte for byte."""
+    P = arena.shape[1]
+    lane = jnp.arange(seg, dtype=jnp.int32)
+    pad = jnp.zeros(seg, jnp.uint8)
+
+    def body(i, out):
+        row, s, d, n = _window_at(desc, i, seg, P)
+        win = jax.lax.dynamic_slice(arena, (row, s), (1, seg))[0]
+        shifted = jax.lax.dynamic_slice(jnp.concatenate([win, pad]),
+                                        (d,), (seg,))
+        return out.at[i].set(jnp.where(lane < n, shifted, 0))
+
+    k = desc.shape[0]
+    return jax.lax.fori_loop(0, k, body, jnp.zeros((k, seg), jnp.uint8))
 
 
 #: elementwise combine (window ⊕ payload) per reduction op, shared by
@@ -691,11 +767,17 @@ def plan_cache_stats() -> Dict[str, int]:
 def scatter_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
                  flat_len: int, *, ordered: bool, impl: str = "ref",
                  donate: bool = True, sseg: Optional[int] = None,
-                 cb: Optional[int] = None) -> Tuple[Callable, bool]:
+                 cb: Optional[int] = None, window: bool = False
+                 ) -> Tuple[Callable, bool]:
     """fn(arena, desc, flat) -> arena'. ``ordered`` keeps the
     sequential loop (overlapping uniform runs); otherwise the
     vectorized unique-index scatter runs.  The Pallas impl is
     inherently ordered (sequential grid) so one kernel serves both.
+
+    ``window`` (ref only) selects the window kernel
+    (:func:`_win_scatter`) for an all-contiguous run with ``seg <=
+    pool_bytes`` on an arena held by one device; it applies in queue
+    order, so ``ordered`` does not split its plans.
 
     ``(sseg, cb)`` are the :func:`strided_buckets` of the run —
     **Pallas-only** grid parameters, defaulting to the contiguous
@@ -703,16 +785,20 @@ def scatter_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
     descriptor table itself (ONE traced formula), so ref callers pass
     ``None`` and a varying-stride loop never leaves the cached plan.
     """
-    check_flat_addressable(arena_shape)
+    if not window:
+        check_flat_addressable(arena_shape)
     sseg = seg if sseg is None else sseg
     cb = 1 if cb is None else cb
+    ordered = ordered and not window
     key = ("scatter", impl, arena_shape, kb, seg, flat_len, ordered,
-           donate, sseg, cb)
+           donate, sseg, cb, window)
 
     def build():
         if impl == "pallas":
             fn = _named("dart_scatter_pallas", _pallas_scatter, seg=seg,
                         sseg=sseg, cb=cb)
+        elif window:
+            fn = _named("dart_scatter_window", _win_scatter, seg=seg)
         elif ordered:
             fn = _named("dart_scatter_ordered", _ref_scatter_ordered,
                         seg=seg)
@@ -782,21 +868,28 @@ def accumulate_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
 
 def gather_plan(arena_shape: Tuple[int, int], kb: int, seg: int, *,
                 impl: str = "ref", sseg: Optional[int] = None,
-                cb: Optional[int] = None) -> Tuple[Callable, bool]:
+                cb: Optional[int] = None, window: bool = False
+                ) -> Tuple[Callable, bool]:
     """fn(arena, desc) -> (kb, >=seg) uint8 pad-to-bucket windows; each
     op's bytes pack densely from column 0 of its row (decode reads the
     first ``nbytes``).  ``(sseg, cb)`` as in :func:`scatter_plan`:
     Pallas-only, ``None`` (→ ``(seg, 1)``) for the ref impl and for
-    contiguous Pallas runs, whose rows stay exactly ``seg`` wide."""
-    check_flat_addressable(arena_shape)
+    contiguous Pallas runs, whose rows stay exactly ``seg`` wide.
+    ``window`` (ref only) selects :func:`_win_gather`, under the same
+    rule as :func:`scatter_plan`; its rows are byte-identical."""
+    if not window:
+        check_flat_addressable(arena_shape)
     sseg = seg if sseg is None else sseg
     cb = 1 if cb is None else cb
-    key = ("gather", impl, arena_shape, kb, seg, sseg, cb)
+    key = ("gather", impl, arena_shape, kb, seg, sseg, cb, window)
 
     def build():
         if impl == "pallas":
             return jax.jit(_named("dart_gather_pallas", _pallas_gather,
                                   seg=seg, sseg=sseg, cb=cb))
+        if window:
+            return jax.jit(_named("dart_gather_window", _win_gather,
+                                  seg=seg))
         return jax.jit(_named("dart_gather", _ref_gather, seg=seg))
 
     return cached_plan(key, build)

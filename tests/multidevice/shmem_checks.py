@@ -174,6 +174,12 @@ ok = all(np.all(np.asarray(
     dart_get_blocking(ctx, gp.setunit(u), (8,), jnp.float32)) == u)
     for u in range(N))
 check("sharded_heap_putget", ok)
+# a row-sharded arena keeps the flat lane plans: window plans would
+# all-gather it
+stats = ctx.engine.dispatch_stats()
+check("sharded_heap_lane_path",
+      stats["dispatches"]["ref"] >= 2 * N
+      and stats["window_dispatches"] == 0)
 shard_rows = {d: s for d, s in zip(
     ctx.state[1].sharding.device_set,
     [None] * N)}

@@ -35,6 +35,7 @@ def test_shmem_and_team_collectives():
     assert "CHECK:shmem_put_ring:OK" in out
     assert "CHECK:team_psum:OK" in out
     assert "CHECK:sharded_heap_putget:OK" in out
+    assert "CHECK:sharded_heap_lane_path:OK" in out
 
 
 def test_pallas_comm_kernels_vs_oracle():

@@ -14,6 +14,8 @@ off around these compiles, since a cache entry for a described chip
 cannot be read back without one.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -108,6 +110,50 @@ def test_gather_plan_compiles(arena_spec, kb, seg):
     fn, _ = sc.gather_plan(shape, kb, seg, impl=onesided.CommEngine().impl)
     _compile(fn, _sds(shape, jnp.uint8, arena_sh),
              _sds((kb, sc.DESC_COLS), jnp.int32, small))
+
+
+#: the only HLO ops a one-chip window plan may apply to an arena-shaped
+#: array: it passes through the loop and takes one window update per
+#: descriptor, and is never copied, broadcast, padded or reshaped
+_WINDOW_ARENA_OPS = {"parameter", "get-tuple-element", "tuple", "while",
+                     "dynamic-update-slice"}
+
+
+@pytest.mark.parametrize("kb,seg", RUN_SHAPES)
+@pytest.mark.parametrize("kind", ["scatter", "gather"])
+def test_put_get_plans_the_engine_picks(arena_spec, kb, seg, kind):
+    """The engine's choice on each layout, compiled: one chip takes the
+    window plans, which never flatten the arena (no ``u8[R*P]``), keep
+    the donated arena in place and ask for scratch far under it; the
+    row-sharded four-chip arena keeps the lane plans, and neither
+    layout's pick holds an all-gather."""
+    shape, arena_sh, small = arena_spec
+    arena = _sds(shape, jnp.uint8, arena_sh)
+    desc = np.zeros((kb, sc.DESC_COLS), np.int32)
+    desc[:, sc.COUNT] = 1
+    window = onesided._window_path(desc, seg, arena)
+    assert window == (len(arena_sh.device_set) == 1)
+    descs = _sds((kb, sc.DESC_COLS), jnp.int32, small)
+    if kind == "scatter":
+        flat = max(kb * seg + seg, sc.FLAT_FLOOR)
+        fn, _ = sc.scatter_plan(shape, kb, seg, flat, ordered=False,
+                                window=window)
+        compiled = _compile(fn, arena, descs,
+                            _sds((flat,), jnp.uint8, small))
+    else:
+        fn, _ = sc.gather_plan(shape, kb, seg, window=window)
+        compiled = _compile(fn, arena, descs)
+    hlo = compiled.as_text()
+    assert "all-gather" not in hlo
+    if window:
+        rows, pool = shape
+        assert f"u8[{rows * pool}]" not in hlo
+        arena_ops = set(re.findall(
+            rf"= u8\[{rows},{pool}\]\{{[^}}]*\}} ([\w-]+)\(", hlo))
+        assert "parameter" in arena_ops and arena_ops <= _WINDOW_ARENA_OPS, \
+            arena_ops
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < rows * pool // 64)
 
 
 @pytest.mark.parametrize("op,dtype,fetch,ordered", [
