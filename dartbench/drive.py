@@ -126,7 +126,8 @@ class Driver:
         passed and a whole kind cycle (``Traffic.period``) is done; an
         epoch started before then runs to its end.  A traced stretch
         lasts ``tracer.span_s`` and holds at least one epoch of every
-        kind."""
+        kind; the window does not close before its stretch has begun,
+        even where one epoch outlasts ``seconds``."""
         t = self.traffic
         n, period = t.n_epochs, t.period
         kinds_all = set(np.unique(t.kind).tolist())
@@ -141,7 +142,7 @@ class Driver:
         deadline = begin + seconds
         while True:
             now = clock()
-            if now >= deadline and k % period == 0:
+            if now >= deadline and k % period == 0 and state != "before":
                 break
             if state == "before" and now - begin >= tracer.skip_s:
                 tracer.begin()

@@ -1,0 +1,6 @@
+"""``lat_p95_us`` (``metrics/lat_p95_us.py``) of a cell on four chips: the
+same reading under a bound of its own, fitted to that cell's spread."""
+
+from dartbench import plugins
+
+read = plugins.load("metrics", "lat_p95_us").read

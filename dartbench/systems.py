@@ -22,8 +22,10 @@ from .reference import Mirror
 
 
 class DartSystem:
-    """One ``GlobalArray`` that spans each unit's whole team window, the
-    units as the rows of one arena on the first device.  The array is
+    """One ``GlobalArray`` that spans each unit's whole team window.  The
+    run's devices (the cell's chips) place the units: on one device, as
+    the rows of one arena there; on several, one unit per device, the
+    arena row-sharded over a ``unit`` mesh of them.  The array is
     allocated with ``shm=False`` so that every op takes the engine's
     device path on every backend, as it does on a chip."""
 
@@ -38,7 +40,15 @@ class DartSystem:
         dcfg = DartConfig(team_pool_bytes=window,
                           non_collective_pool_bytes=int(
                               config["world_pool_bytes_per_unit"]))
-        self.ctx = dart_init(n_units=self.units, config=dcfg)
+        if len(devices) <= 1:
+            self.ctx = dart_init(n_units=self.units, config=dcfg)
+        else:
+            from repro.launch.mesh import make_mesh
+            if len(devices) != self.units:
+                raise ValueError(f"{len(devices)} devices need one unit each: "
+                                 f"the deployment has {self.units}")
+            mesh = make_mesh((self.units,), ("unit",), devices=devices)
+            self.ctx = dart_init(mesh=mesh, unit_axes=("unit",), config=dcfg)
         self.elems = window // self.dtype.itemsize
         self.ga = self.ctx.alloc((self.elems,), jnp.dtype(self.dtype),
                                  shm=False)

@@ -12,6 +12,7 @@ import pytest
 
 from dartbench import run as run_mod
 from dartbench import systems
+import repro.core
 from repro.core import tracing
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -73,8 +74,11 @@ def test_reader_gives_nothing_without_its_span(monkeypatch, metric):
 @pytest.mark.parametrize("metric", sorted(PROGRAM))
 def test_reader_gives_nothing_for_a_program_without_tracing(
         monkeypatch, metric):
-    # an older checkout: ``repro.core.tracing`` does not import
+    # an older checkout: ``repro.core.tracing`` does not import (the
+    # package attribute goes too, or the import would find the module
+    # that this process has already loaded)
     monkeypatch.setitem(sys.modules, "repro.core.tracing", None)
+    monkeypatch.delattr(repro.core, "tracing")
     assert _read(metric, _Run()) is None
 
 

@@ -1,17 +1,24 @@
-"""Each cell's run, end to end on the CPU at a tiny size: correct against
-the reference as the program stands, not correct under the control and
-under each fault the cell can have, and no result without a TPU."""
+"""Each one-chip cell's run, end to end on the CPU at a tiny size: correct
+against the reference as the program stands, not correct under the
+control and under each fault the cell can have, and no result without a
+TPU.  Cells on four chips run the same checks in a child process with
+four virtual devices (``test_bench_four_chip.py``)."""
 
+import dataclasses
 import json
 import pathlib
 import re
 import subprocess
 import sys
+import time
+import types
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from dartbench import run, systems
+from dartbench import drive, run, systems
 from repro.core import onesided
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -34,13 +41,16 @@ def _run(cell_name, trace=False, factory=systems.DartSystem, seconds=0.5):
     return run.run_cell(cell, config, mix,
                         run.metrics_of(bench, cell, trace), seed=SEED,
                         seconds=seconds, trace=trace,
-                        devices=jax.devices()[:1],
+                        devices=jax.devices()[:cell["chips"]],
                         peaks={"hbm_bytes_per_s": 819e9},
                         system_factory=factory)
 
 
-CELLS = [w["name"] for w in json.loads(
-    (ROOT / "BENCHMARK.json").read_text())["workloads"]]
+WORKLOADS = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]
+#: cells run in this process, on its one device
+CELLS = [w["name"] for w in WORKLOADS if w["chips"] == 1]
+#: cells run on four virtual devices in a child process
+FOUR_CHIP_CELLS = [w["name"] for w in WORKLOADS if w["chips"] == 4]
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -90,25 +100,54 @@ def _altered_answer(raw, shape, dtype):
     return out
 
 
+def _row_shifted(self, arena, run, disjoint=True):
+    rows = arena.shape[0]
+    return _ORIG_PUT(self, arena, [
+        dataclasses.replace(op, row=(op.row + 1) % rows) for op in run],
+        disjoint)
+
+
+_ORIG_GET = onesided.CommEngine._dispatch_get_run
+
+
+def _exchange_left_out(self, arena, run):
+    """The gather as the first device computes it alone: the rows that
+    the other devices hold read as zeros."""
+    held = arena.addressable_shards[0].index[0]
+    rows = jnp.arange(arena.shape[0])[:, None]
+    mine = (rows >= (held.start or 0)) & (rows < (held.stop or len(rows)))
+    return _ORIG_GET(self, jnp.where(mine, arena, jnp.uint8(0)), run)
+
+
 FAULTS = [
-    # (fault, where it is planted, cells that can have it; the exchange
-    # between chips is a fault only a cell on several chips can have)
-    ("state_unchanged", "_dispatch_put_run", _unchanged, CELLS),
+    # (fault, where it is planted, cells that can have it; a put landing
+    # in another chip's row and the exchange between chips are faults
+    # only a cell on several chips can have)
+    ("state_unchanged", "_dispatch_put_run", _unchanged,
+     CELLS + FOUR_CHIP_CELLS),
     ("half_batch", "_dispatch_put_run", _half_batch,
      ["rate-small", "bw-large"]),
     ("answer_altered", "_host_decode", _altered_answer,
-     ["lat-small", "bw-large"]),
+     ["lat-small", "bw-large", "lat-small-4chip"]),
+    ("row_shifted", "_dispatch_put_run", _row_shifted, ["lat-small-4chip"]),
+    ("exchange_left_out", "_dispatch_get_run", _exchange_left_out,
+     ["lat-small-4chip"]),
 ]
 
 
-@pytest.mark.parametrize("cell,fault", [
-    (c, f) for f, _, _, cells in FAULTS for c in cells])
-def test_fault_makes_the_run_not_correct(monkeypatch, cell, fault):
+def plant(monkeypatch, fault: str) -> None:
+    """Plant ``fault`` in the program for the rest of the test."""
     _, attr, fn, _ = next(f for f in FAULTS if f[0] == fault)
     if attr == "_host_decode":
         monkeypatch.setattr(onesided, attr, fn)
     else:
         monkeypatch.setattr(onesided.CommEngine, attr, fn)
+
+
+@pytest.mark.parametrize("cell,fault", [
+    (c, f) for f, _, _, cells in FAULTS for c in cells if c in CELLS])
+def test_fault_makes_the_run_not_correct(monkeypatch, cell, fault):
+    plant(monkeypatch, fault)
     res = _run(cell)
     assert not res["correct"], (fault, res["check"])
 
@@ -131,6 +170,35 @@ def test_run_refuses_to_measure_without_a_tpu():
     assert p.returncode != 0
     assert p.stdout.strip() == ""
     assert "no TPU" in p.stderr
+
+
+def test_traced_window_begins_its_stretch_when_one_epoch_outlasts_it():
+    # one epoch of 50 ms against a window of 10 ms: the stretch still
+    # begins (after the first epoch) and ends, and holds an epoch
+    class _Slow(drive.Driver):
+        def issue(self, e):
+            time.sleep(0.05)
+
+    class _Tracer:
+        skip_s, span_s = 0.002, 0.004
+
+        def __init__(self):
+            self.calls = []
+
+        def begin(self):
+            self.calls.append("begin")
+
+        def end(self):
+            self.calls.append("end")
+
+    traffic = types.SimpleNamespace(n_epochs=2, period=1,
+                                    kind=np.zeros(2, np.int64))
+    system = types.SimpleNamespace(counters=lambda: {})
+    tracer = _Tracer()
+    win = _Slow(system, traffic).window(0.01, tracer)
+    assert tracer.calls == ["begin", "end"]
+    i0, i1 = win.stretch
+    assert i1 - i0 >= 1
 
 
 # -- BENCHMARK.json against the benchmark's contract ----------------------

@@ -103,3 +103,49 @@ def test_union_and_gaps_edges():
     assert tr.gaps([(2, 4), (6, 8)], 0, 10) == [(0, 2), (4, 6), (8, 10)]
     assert tr.gaps([], 0, 10) == [(0, 10)]
     assert np.isclose(tr.total([(0, 4), (6, 8)]), 6)
+
+
+def test_collective_time_per_dispatch_on_two_devices():
+    # device A: an all-reduce [100, 200) overlapping its async start
+    # [150, 250) -> 150 ns, a fusion that reads the all-reduce's result
+    # (no collective); device B: all-gather [0, 50) and a mangled
+    # collective-permute-done [900, 1200) clipped to 1000 -> 150 ns.
+    # The mean over the two devices is 150 ns.
+    ops = {"/device:TPU:0": [
+               ("%all-reduce.3 = f32[4]{0} all-reduce(%p)", 100, 200),
+               ("all-reduce-start.1", 150, 250),
+               ("%fusion.5 = f32[4]{0} fusion(%all-reduce.3)", 300, 600)],
+           "/device:TPU:1": [
+               ("_all-gather.2___u8_4_128", 0, 50),
+               ("_collective_permute_done.7___u8", 900, 1200),
+               ("_dynamic_update_slice.11___u8", 60, 800)]}
+    trace = tr.Trace(0, 1000, ops, [])
+    assert [tr.is_collective(n) for n, _, _ in ops["/device:TPU:0"]] == [
+        True, True, False]
+    assert not tr.is_collective("reduce.4")
+    assert not tr.is_collective("_while.10")
+    assert tr.is_collective("reduce-scatter.1") and tr.is_collective(
+        "all-to-all.2")
+    assert trace.collective_mean_s() == pytest.approx(150e-9)
+    run = _Run(trace, {"bytes": 0, "ops": 3, "dispatches": 3})
+    assert _read("collective_us_per_dispatch.lat4", run) == pytest.approx(
+        150e-9 / 3 * 1e6)
+    quiet = tr.Trace(0, 1000, {"/device:TPU:0": [("fusion.1", 0, 10)]}, [])
+    assert _read("collective_us_per_dispatch.lat4",
+                 _Run(quiet, {"bytes": 0, "ops": 1, "dispatches": 1})) is None
+    assert _read("collective_us_per_dispatch.lat4", _Run(None, None)) is None
+
+
+def test_rehearsal_counts_collective_opcodes():
+    from dartbench import rehearse
+    hlo = """
+  %all-reduce.1 = u8[4,16]{1,0} all-reduce(u8[4,16]{1,0} %f), to_apply=%a
+  %fusion.3 = u8[64]{0} fusion(u8[4,16]{1,0} %all-reduce.1), kind=kLoop
+  %ag = (u8[4]{0}, u8[16]{0}) all-gather-start(u8[4]{0} %p), dimensions={0}
+  %agd = u8[16]{0} all-gather-done((u8[4]{0}, u8[16]{0}) %ag)
+  %r = f32[] reduce(f32[8]{0} %x, f32[] %z), to_apply=%add
+"""
+    assert rehearse.collectives(hlo) == {"all-reduce": 1,
+                                         "all-gather-start": 1,
+                                         "all-gather-done": 1}
+    assert rehearse.collectives("%w = s32[] while(s32[] %i)") == {}

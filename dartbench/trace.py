@@ -27,8 +27,22 @@ DEVICE_OP_LINE = "XLA Ops"
 _NAME_CHARS = 160
 #: spans searched back from a gap for one that covers it
 _LOOK_BACK = 32
+#: HLO opcodes that move data between devices; an operation is one of
+#: them, or its async ``-start``/``-done`` half, by its instruction name
+COLLECTIVES = ("all-reduce", "all-gather", "collective-permute",
+               "all-to-all", "reduce-scatter")
 
 Interval = Tuple[float, float]
+
+
+def is_collective(name: str) -> bool:
+    """Whether a device operation's name is a collective's: its
+    instruction name (the leading word, past a ``%`` or ``_``, with
+    ``_`` read as ``-``) starts with one of :data:`COLLECTIVES`.  An
+    operation that only takes a collective's result as an operand reads
+    as no collective."""
+    head = name.lstrip("%_").replace("_", "-")
+    return head.startswith(COLLECTIVES)
 
 
 def union(intervals: Sequence[Interval], lo: float, hi: float
@@ -104,6 +118,15 @@ class Trace:
     def busy_mean_s(self) -> float:
         per = self.busy_per_device_s()
         return sum(per.values()) / len(per) if per else 0.0
+
+    def collective_mean_s(self) -> float:
+        """Seconds in which a collective ran, the union over each
+        device's collective operations, averaged over the devices of the
+        run."""
+        per = [total(union([(s, e) for n, s, e in ops if is_collective(n)],
+                           self.lo, self.hi))
+               for ops in self.device_ops.values()]
+        return sum(per) / len(per) * 1e-9 if per else 0.0
 
     def span_durations_s(self, name: str) -> List[float]:
         return [(e - s) * 1e-9 for n, s, e in self.spans
