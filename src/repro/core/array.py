@@ -269,13 +269,17 @@ class GlobalRef:
         ``MPI_Accumulate`` analogue): queued on the engine; consecutive
         same-``op`` accumulates coalesce into ONE read-modify-write
         dispatch at the next epoch close — overlapping runs included
-        (the ops commute).  Returns the Handle."""
+        (the ops commute).  ``op`` is one of ``sum``, ``prod``,
+        ``min``, ``max`` and ``bxor`` (int32/uint32 only; any other
+        dtype raises ``TypeError`` here).  Traced as ``dart.accumulate``
+        with its element count.  Returns the Handle."""
         from . import runtime as rt
         if self.size == 0:
             return self._empty_handle()
-        return rt.dart_accumulate(self.array.ctx, self.gptr,
-                                  self._coerce(value), op,
-                                  **self._byte_geom())
+        with tracing.span("dart.accumulate", elems=self.size):
+            return rt.dart_accumulate(self.array.ctx, self.gptr,
+                                      self._coerce(value), op,
+                                      **self._byte_geom())
 
     def add(self, value):
         """``ref.add(v)`` ≡ ``ref.accumulate(v, "sum")``."""

@@ -234,8 +234,13 @@ def _row_gather_typed_plan(arena_shape, dtype, eb: int):
     return _sc.cached_plan(key, build)
 
 
+def _bxor_reduce(vals, axis):
+    return jax.lax.reduce(vals, np.asarray(0, vals.dtype),
+                          jax.lax.bitwise_xor, (axis,))
+
+
 _REDUCERS = {"sum": jnp.sum, "max": jnp.max, "min": jnp.min,
-             "prod": jnp.prod}
+             "prod": jnp.prod, "bxor": _bxor_reduce}
 
 
 def _reduce_plan(arena_shape, eb: int, dtype, op: str, root: bool,
@@ -432,6 +437,7 @@ def dart_scatter_typed(state: HeapState, heap: SymmetricHeap, teams_by_slot,
 def _run_reduce(state, heap, teams_by_slot, gptr, shape, dtype, op,
                 engine, root_unit):
     dt = jnp.dtype(dtype)
+    _sc.check_op_dtype(op, dt)      # before the flush: nothing queued moves
     shape = tuple(shape)
     n_elems = int(np.prod(shape, dtype=np.int64)) if shape else 1
     if root_unit is None:

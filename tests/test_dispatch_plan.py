@@ -102,6 +102,25 @@ def test_op_identity_table():
         sc.op_identity("xor", jnp.int32)
 
 
+@pytest.mark.parametrize("dtype,ok", [("int32", True), ("uint32", True),
+                                      ("float32", False), ("int16", False)])
+def test_bxor_identity_is_zero_on_int32_and_uint32_only(dtype, ok):
+    """``bxor`` (MPI_BXOR) has identity 0, and exists for int32 and
+    uint32 alone: elsewhere its identity, its payload staging and its
+    plan are a TypeError."""
+    if ok:
+        assert sc.op_identity("bxor", dtype) == 0
+        assert not sc.identity_bytes("bxor", dtype).any()
+        sc.accumulate_plan((2, 256), 4, 16, 64, op="bxor", dtype=dtype,
+                           fetch=False)
+        return
+    with pytest.raises(TypeError, match="bxor"):
+        sc.op_identity("bxor", dtype)
+    with pytest.raises(TypeError, match="bxor"):
+        sc.accumulate_plan((2, 256), 4, 16, 64, op="bxor", dtype=dtype,
+                           fetch=False)
+
+
 def test_accumulate_padding_descriptors_do_not_touch_arena():
     """len=0 accumulate descriptors (bucket padding) must leave every
     arena byte untouched under both impls — masked lanes are dropped

@@ -39,6 +39,10 @@ POOL = 2048
 N_UNITS = 4
 
 OPS = ("sum", "prod", "min", "max")
+#: (op, dtype) of the differential loop: each of OPS on int32 and
+#: float32, and bxor, which takes int32 and uint32 only
+OP_DTYPES = [(op, dt) for op in OPS for dt in ("int32", "float32")] + [
+    ("bxor", "int32"), ("bxor", "uint32")]
 
 
 def _mk_ctx(impl="ref", pool=POOL):
@@ -78,6 +82,8 @@ class Oracle:
             new = cur * vals
         elif op == "min":
             new = np.minimum(cur, vals)
+        elif op == "bxor":
+            new = cur ^ vals
         else:
             new = np.maximum(cur, vals)
         self.arena[row, off:off + n] = new.astype(dt).view(np.uint8)
@@ -100,8 +106,7 @@ def _device_arena(ctx):
 
 # ------------------------------------------- the differential loop --------
 
-@pytest.mark.parametrize("dtype", ["int32", "float32"])
-@pytest.mark.parametrize("op", OPS)
+@pytest.mark.parametrize("op,dtype", OP_DTYPES)
 def test_differential_sequences_vs_blocking_oracle(op, dtype, engine_impl):
     """≥ 200 generated op sequences per op class (100 here × 2 engine
     impls): random interleavings of accumulate (dominant), put,
@@ -314,6 +319,21 @@ def test_unknown_op_rejected_at_initiation(ctx):
     assert ctx.engine.pending_ops() == 0
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int16"])
+def test_bxor_refused_at_initiation_off_int32_and_uint32(ctx, dtype):
+    """MPI defines BXOR for integers only, and the reduction plane takes
+    it on int32 and uint32: any other dtype is a TypeError when the op
+    is issued, through the raw call and the typed front end alike, and
+    nothing is queued."""
+    g = dart_memalloc(ctx, 256, unit=0)
+    with pytest.raises(TypeError, match="bxor"):
+        dart_accumulate(ctx, g, jnp.ones((2,), dtype), "bxor")
+    ga = ctx.alloc((4,), jnp.dtype(dtype), shm=False)
+    with pytest.raises(TypeError, match="bxor"):
+        ga.at[1, 0:2].accumulate(jnp.ones((2,), dtype), "bxor")
+    assert ctx.engine.pending_ops() == 0
+
+
 def test_misaligned_accumulate_rejected(ctx):
     g = dart_memalloc(ctx, 256, unit=0)
     with pytest.raises(ValueError):
@@ -390,6 +410,45 @@ def test_allreduce_sees_queued_puts(ctx):
     np.testing.assert_array_equal(red, [10.0, 10.0])
 
 
+@pytest.mark.parametrize("dtype", ["int32", "uint32"])
+@pytest.mark.parametrize("root", [None, 1])
+def test_bxor_allreduce_and_reduce_match_numpy(ctx, dtype, root):
+    """``bxor`` reduces across rows as numpy's ``^`` does, bit for bit,
+    over a non-pow2 element count (the padded lanes read 0)."""
+    g = dart_team_memalloc_aligned(ctx, DART_TEAM_ALL, 256)
+    rng = np.random.default_rng(5)
+    vals = rng.integers(np.iinfo(dtype).min, np.iinfo(dtype).max,
+                        size=(N_UNITS, 5), dtype=dtype, endpoint=True)
+    for u in range(N_UNITS):
+        dart_put_blocking(ctx, g.setunit(u), jnp.asarray(vals[u]))
+    expect = np.bitwise_xor.reduce(vals, axis=0)
+    if root is None:
+        red = dart_allreduce(ctx, g, (5,), dtype, "bxor")
+    else:
+        red = dart_reduce(ctx, g, (5,), dtype, "bxor", root=root)
+    np.testing.assert_array_equal(np.asarray(red), expect)
+    for u in range(N_UNITS):
+        got = np.asarray(dart_get_blocking(ctx, g.setunit(u), (5,),
+                                           dtype))
+        want = expect if root in (None, u) else vals[u]
+        np.testing.assert_array_equal(got, want)
+
+
+def test_bxor_allreduce_refuses_a_float_before_flushing(ctx):
+    """The op/dtype check comes before the collective's flush: a
+    refused allreduce leaves the queue and the dispatch count as they
+    were."""
+    g = dart_team_memalloc_aligned(ctx, DART_TEAM_ALL, 128)
+    dart_put(ctx, g.setunit(0), jnp.ones((2,), jnp.float32))
+    d0 = ctx.engine.dispatch_count
+    with pytest.raises(TypeError):
+        dart_allreduce(ctx, g, (2,), jnp.float32, "bxor")
+    with pytest.raises(ValueError):
+        dart_allreduce(ctx, g, (2,), jnp.float32, "land")
+    assert ctx.engine.dispatch_count == d0
+    assert ctx.engine.pending_ops() == 1
+
+
 def test_scalar_allreduce(ctx):
     g = dart_team_memalloc_aligned(ctx, DART_TEAM_ALL, 64)
     for u in range(N_UNITS):
@@ -438,11 +497,62 @@ def test_accumulate_zero_recompiles_steady_state(ctx):
         dart_waitall(hs)
 
     epoch(8, 16)                                  # warm (8, 64B) bucket
+    epoch(4, 16)                                  # and (4, 64B)
     c0 = ctx.engine.compile_count
     for k, n in [(5, 16), (7, 9), (8, 12), (6, 10), (4, 16), (8, 13)]:
         epoch(k, n)
     assert ctx.engine.compile_count == c0, \
         "varying-size accumulate epochs recompiled in steady state"
+
+
+@pytest.mark.parametrize("placement", ["disjoint", "overlapping",
+                                       "duplicate"])
+@pytest.mark.parametrize("dtype", ["int32", "uint32"])
+def test_bxor_matches_numpy_in_one_dispatch_per_epoch(ctx, dtype,
+                                                      placement):
+    """``accumulate(v, "bxor")`` on full-width words equals numpy's
+    ``^`` bit for bit: on disjoint ranges, on overlapping ones (the
+    ordered path: the window loop in queue order, or the Pallas grid)
+    and on one 64-bit word hit many times in an epoch.  Every epoch is one dispatch, and once warm a loop of
+    epochs compiles nothing."""
+    dt = np.dtype(dtype)
+    n_elems = 256
+    ga = ctx.alloc((n_elems,), jnp.dtype(dt), shm=False)
+    rng = np.random.default_rng(sum(map(ord, dtype + placement)))
+    want = np.zeros((N_UNITS, n_elems), dt)
+
+    def ranges():
+        if placement == "disjoint":
+            slots = rng.choice(N_UNITS * n_elems // 4, 16, replace=False)
+            return [(int(s) // (n_elems // 4), int(s) % (n_elems // 4) * 4,
+                     int(rng.integers(1, 5))) for s in slots]
+        if placement == "overlapping":
+            return [(int(rng.integers(2)), int(rng.integers(0, 8)),
+                     int(rng.integers(1, 5))) for _ in range(16)]
+        words = [(int(rng.integers(N_UNITS)),
+                  2 * int(rng.integers(n_elems // 2))) for _ in range(3)]
+        return [words[i % 3] + (2,) for i in range(16)]
+
+    def epoch():
+        hs = []
+        for u, lo, n in ranges():
+            v = rng.integers(0, 2**32, n, dtype=np.uint64).astype(
+                np.uint32).view(dt)
+            hs.append(ga.at[u, lo:lo + n].accumulate(v, "bxor"))
+            want[u, lo:lo + n] ^= v
+        d0 = ctx.engine.dispatch_count
+        ga.flush()
+        assert ctx.engine.dispatch_count - d0 == 1
+        dart_waitall(hs)
+
+    epoch()
+    c0 = ctx.engine.compile_count
+    for _ in range(4):
+        epoch()
+    assert ctx.engine.compile_count == c0
+    got = np.stack([np.asarray(ga.at[u, 0:n_elems].get())
+                    for u in range(N_UNITS)])
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
 
 
 # ---------------------------------------------------- typed front-end -----

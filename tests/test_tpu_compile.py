@@ -37,6 +37,10 @@ FOUR_CHIP_ARENA = (4, 16 * MiB)
 #: (run-length bucket, segment bucket): a blocking op, and the smoke's
 #: coalesced epoch to all 8 units
 RUN_SHAPES = [(4, 16), (8, 8192)]
+#: the GUPS cell's table on one chip: 4 units of 1 GiB, past the lane
+#: plans' int32 index; and its epoch of 1024 updates of 8 bytes
+GUPS_ARENA = (4, 1 << 30)
+GUPS_RUN = (1024, 16)
 
 
 @pytest.fixture(scope="module")
@@ -119,14 +123,31 @@ _WINDOW_ARENA_OPS = {"parameter", "get-tuple-element", "tuple", "while",
                      "dynamic-update-slice"}
 
 
+def _check_window_plan(compiled, shape, donated=True):
+    """A one-chip window plan's program: the arena is never flattened
+    (no ``u8[R*P]``) and takes its window updates in place: a donated
+    arena is the output's alias, and scratch is far under it."""
+    hlo = compiled.as_text()
+    rows, pool = shape
+    assert f"u8[{rows * pool}]" not in hlo
+    arena_ops = set(re.findall(
+        rf"= u8\[{rows},{pool}\]\{{[^}}]*\}} ([\w-]+)\(", hlo))
+    assert "parameter" in arena_ops and arena_ops <= _WINDOW_ARENA_OPS, \
+        arena_ops
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < rows * pool // 64
+    if donated:
+        assert memory.alias_size_in_bytes == rows * pool
+
+
 @pytest.mark.parametrize("kb,seg", RUN_SHAPES)
-@pytest.mark.parametrize("kind", ["scatter", "gather"])
+@pytest.mark.parametrize("kind", ["scatter", "gather", "accumulate"])
 def test_put_get_plans_the_engine_picks(arena_spec, kb, seg, kind):
     """The engine's choice on each layout, compiled: one chip takes the
-    window plans, which never flatten the arena (no ``u8[R*P]``), keep
-    the donated arena in place and ask for scratch far under it; the
-    row-sharded four-chip arena keeps the lane plans, and neither
-    layout's pick holds an all-gather."""
+    window plans (puts, gets and plain accumulates), which never
+    flatten the arena, keep the donated arena in place and ask for
+    scratch far under it; the row-sharded four-chip arena keeps the
+    lane plans, and neither layout's pick holds an all-gather."""
     shape, arena_sh, small = arena_spec
     arena = _sds(shape, jnp.uint8, arena_sh)
     desc = np.zeros((kb, sc.DESC_COLS), np.int32)
@@ -140,20 +161,33 @@ def test_put_get_plans_the_engine_picks(arena_spec, kb, seg, kind):
                                 window=window)
         compiled = _compile(fn, arena, descs,
                             _sds((flat,), jnp.uint8, small))
+    elif kind == "accumulate":
+        fn, _ = sc.accumulate_plan(shape, kb, seg, kb * seg, op="bxor",
+                                   dtype="uint32", fetch=False,
+                                   window=window)
+        compiled = _compile(fn, arena,
+                            _sds((kb, sc.ACC_DESC_COLS), jnp.int32, small),
+                            _sds((kb * seg,), jnp.uint8, small))
     else:
         fn, _ = sc.gather_plan(shape, kb, seg, window=window)
         compiled = _compile(fn, arena, descs)
-    hlo = compiled.as_text()
-    assert "all-gather" not in hlo
+    assert "all-gather" not in compiled.as_text()
     if window:
-        rows, pool = shape
-        assert f"u8[{rows * pool}]" not in hlo
-        arena_ops = set(re.findall(
-            rf"= u8\[{rows},{pool}\]\{{[^}}]*\}} ([\w-]+)\(", hlo))
-        assert "parameter" in arena_ops and arena_ops <= _WINDOW_ARENA_OPS, \
-            arena_ops
-        assert (compiled.memory_analysis().temp_size_in_bytes
-                < rows * pool // 64)
+        _check_window_plan(compiled, shape, donated=kind != "gather")
+
+
+def test_window_accumulate_compiles_for_the_gups_table(topo):
+    """The GUPS cell's plan at its real size: XOR updates into a 4 GiB
+    one-chip arena, which the lane plans' int32 index refuses, compiled
+    as a window plan that updates the donated table in place."""
+    one = SingleDeviceSharding(topo.devices[0])
+    kb, seg = GUPS_RUN
+    fn, _ = sc.accumulate_plan(GUPS_ARENA, kb, seg, kb * seg, op="bxor",
+                               dtype="uint32", fetch=False, window=True)
+    compiled = _compile(fn, _sds(GUPS_ARENA, jnp.uint8, one),
+                        _sds((kb, sc.ACC_DESC_COLS), jnp.int32, one),
+                        _sds((kb * seg,), jnp.uint8, one))
+    _check_window_plan(compiled, GUPS_ARENA)
 
 
 @pytest.mark.parametrize("op,dtype,fetch,ordered", [

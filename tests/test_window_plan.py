@@ -1,7 +1,8 @@
-"""Window plans: contiguous puts and gets moved as ``(1, seg)`` windows of
-the 2-D arena (``_win_scatter`` / ``_win_gather``), held byte for byte
-to the flat lane plans and to a numpy model, and the engine's choice
-between the two paths."""
+"""Window plans: contiguous puts, gets and plain accumulates moved as
+``(1, seg)`` windows of the 2-D arena (``_win_scatter``, with an op for
+accumulates, and ``_win_gather``), held byte for byte to the flat lane
+plans and to a numpy model, and the engine's choice between the two
+paths."""
 
 import types
 
@@ -159,6 +160,83 @@ def test_window_plans_lift_the_flat_index_limit():
     sc.gather_plan(shape, 4, 16, window=True)
 
 
+#: every (op, dtype) the reduction plane takes that both accumulate
+#: paths can run: each arithmetic op on float32, int32 and uint32, and
+#: bxor on the integers it is defined for
+ACC_CASES = [(op, dt) for op in ("sum", "prod", "min", "max")
+             for dt in ("float32", "int32", "uint32")] + [
+    ("bxor", "int32"), ("bxor", "uint32")]
+
+
+def _accumulate(arena, desc, flat, seg, op, dt, *, window, ordered=False):
+    fn, _ = sc.accumulate_plan(arena.shape, desc.shape[0], seg,
+                               flat.shape[0], op=op, dtype=dt, fetch=False,
+                               ordered=ordered, donate=False, window=window)
+    return np.asarray(fn(jnp.asarray(arena), desc, flat))
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+@pytest.mark.parametrize("op,dtype", ACC_CASES)
+def test_window_accumulate_matches_lane_plans(op, dtype, overlap):
+    """The window accumulate plan leaves the arena byte-equal to the
+    lane plans (vectorized on disjoint runs, ordered on overlapping
+    ones), one descriptor always hard against a row's end (the clamped
+    window).  Values are small integers, so every sum and product is
+    exact in float32 and the order of the lane plan's vectorized
+    combine cannot show."""
+    dt = np.dtype(dtype)
+    rng = np.random.default_rng(sum(map(ord, op + dtype)) + overlap)
+    low = 0 if dt.kind == "u" else -3
+    arena = rng.integers(low, 4, (R, P // 4)).astype(dt).view(np.uint8)
+    k = int(rng.integers(2, 12))
+    lens = rng.integers(1, 17, k)                 # elements
+    if overlap:
+        rows = rng.integers(0, 2, k)
+        offs = np.array([rng.integers(0, P // 4 - n + 1) for n in lens])
+    else:                  # one 64-byte slot each, the first a row's last
+        per_row = P // 64
+        last = int(rng.integers(R)) * per_row + per_row - 1
+        others = np.setdiff1d(np.arange(R * per_row), [last])
+        slots = np.concatenate([[last], rng.choice(others, size=k - 1,
+                                                   replace=False)])
+        rows = slots // per_row
+        offs = (slots % per_row) * 16 + rng.integers(0, 17 - lens)
+    offs[0] = P // 4 - lens[0]
+    pays = [rng.integers(low, 4, int(n)).astype(dt).view(np.uint8)
+            for n in lens]
+    desc, flat, seg = sc.pack_acc_descriptors(
+        rows, offs * 4, lens * 4, pays, op, dt)
+    win = _accumulate(arena, desc, flat, seg, op, dt, window=True,
+                      ordered=overlap)
+    lane = _accumulate(arena, desc, flat, seg, op, dt, window=False,
+                       ordered=overlap)
+    np.testing.assert_array_equal(win, lane)
+    assert not np.array_equal(win, arena)
+
+
+def test_window_accumulate_lowers_for_a_4_gib_arena():
+    """At the GUPS cell's table, 4 rows of 2**30 bytes, the lane plans
+    are refused (their flat int32 index) and the window plan lowers
+    from shapes alone, its arena never flattened; it serves disjoint
+    and overlapping runs alike."""
+    shape, kb, seg = (4, 1 << 30), 1024, 16
+    for ordered in (False, True):
+        with pytest.raises(NotImplementedError):
+            sc.accumulate_plan(shape, kb, seg, kb * seg, op="bxor",
+                               dtype="uint32", fetch=False, ordered=ordered)
+    fn, _ = sc.accumulate_plan(shape, kb, seg, kb * seg, op="bxor",
+                               dtype="uint32", fetch=False, window=True)
+    assert sc.accumulate_plan(shape, kb, seg, kb * seg, op="bxor",
+                              dtype="uint32", fetch=False, ordered=True,
+                              window=True)[0] is fn
+    text = fn.lower(
+        jax.ShapeDtypeStruct(shape, jnp.uint8),
+        jax.ShapeDtypeStruct((kb, sc.ACC_DESC_COLS), jnp.int32),
+        jax.ShapeDtypeStruct((kb * seg,), jnp.uint8)).as_text()
+    assert "tensor<4x1073741824xui8>" in text
+    assert str(4 << 30) not in text
+
+
 # ---------------------------------------------------- the engine's choice --
 
 @pytest.fixture()
@@ -198,6 +276,33 @@ def test_contiguous_runs_take_the_window_path(ga, tmp_path):
     assert n == window == 4                       # 2 put runs, 2 gets
     assert s1["window_dispatches"] - s0["window_dispatches"] == 4
     assert s1["dispatches"]["ref"] - s0["dispatches"]["ref"] == 4
+
+
+def test_plain_accumulates_take_the_window_path(ga, tmp_path):
+    """A plain contiguous accumulate run moves windows; a fetch (its
+    pre-update values come from the lane plan's gather) and a strided
+    run keep the lane plans.  The front end spans every accumulate call
+    as ``dart.accumulate`` with its element count."""
+    eng = ga.ctx.engine
+    w0 = eng.dispatch_stats()["window_dispatches"]
+    ones = np.ones(12, np.float32)
+
+    def ops():
+        hs = [ga.at[0, i * 8:i * 8 + 4].accumulate(ones[:4], "sum")
+              for i in range(3)]
+        ga.flush()
+        for h in hs:
+            h.wait()
+        old = ga.at[0, 0:4].get_accumulate(ones[:4], "sum")
+        np.testing.assert_array_equal(old, ones[:4])
+        ga.at[1, 0:96:8].accumulate(ones, "max").wait()
+        np.testing.assert_array_equal(ga.at[0, 0:4].get(), 2 * ones[:4])
+
+    n, window = _launch_window_counter(tmp_path, ops)
+    assert (n, window) == (4, 2)                  # + the get's window
+    assert eng.dispatch_stats()["window_dispatches"] - w0 == 2
+    acc = tracing.totals()["dart.accumulate"]
+    assert (acc["n"], acc["elems"]) == (4, 3 * 4 + 12)
 
 
 def test_strided_runs_take_the_lane_path(ga, tmp_path):
