@@ -188,23 +188,36 @@ class GlobalRef:
         return GlobalRef(self.array, self.unit, self.offset + off, shp,
                          seg, stride, count)
 
-    def _coerce(self, value) -> jax.Array:
-        """``value`` as a device array of this ref's dtype and shape, in
-        a ``dart.coerce`` span that counts the host->device bytes of a
-        host input."""
-        with tracing.span("dart.coerce") as sp:
-            v = jnp.asarray(value, dtype=self.dtype)
-            if sp.on and not isinstance(value, jax.Array):
-                sp.add(h2d_bytes=int(v.nbytes))
-            if v.shape == self.shape:
-                return v
-            if v.ndim == 0:
-                return jnp.broadcast_to(v, self.shape)
-            if v.size == int(np.prod(self.shape, dtype=np.int64)):
-                return v.reshape(self.shape)
-        raise ValueError(
-            f"value of shape {v.shape} does not fit ref of shape "
-            f"{self.shape}")
+    def _coerce(self, value) -> Union[np.ndarray, jax.Array]:
+        """``value`` as an array of this ref's dtype and shape, in a
+        ``dart.coerce`` span.
+
+        A numpy array or scalar already of the ref's dtype stays on the
+        host: the result is a private C-contiguous copy, so the bytes
+        are captured here and the engine, which stages numpy input as
+        a view, never holds the caller's buffer (counter ``host`` 1).
+        Anything else (``jax.Array``, Python scalars and lists, numpy of
+        another dtype) goes through ``jnp.asarray``, which owns the
+        conversion rules (``host`` 0); ``h2d_bytes`` counts the
+        host->device bytes that conversion copies."""
+        host = (isinstance(value, (np.ndarray, np.generic))
+                and value.dtype == self.dtype)
+        with tracing.span("dart.coerce", host=int(host)) as sp:
+            xp = np if host else jnp
+            v = value if host else jnp.asarray(value, dtype=self.dtype)
+            if sp.on:
+                up = not host and not isinstance(value, jax.Array)
+                sp.add(h2d_bytes=int(v.nbytes) if up else 0)
+            if v.shape != self.shape:
+                if v.ndim == 0:
+                    v = xp.broadcast_to(v, self.shape)
+                elif v.size == self.size:
+                    v = v.reshape(self.shape)
+                else:
+                    raise ValueError(
+                        f"value of shape {v.shape} does not fit ref of "
+                        f"shape {self.shape}")
+            return np.array(v, copy=True, order="C") if host else v
 
     def _empty_handle(self):
         """A born-complete Handle for zero-element refs: nothing moves,

@@ -36,11 +36,12 @@ def ga():
     dart_exit(ctx)
 
 
-def _epoch_and_get(ga):
-    """One put_nb epoch, completed, then a blocking get of its third
-    put; returns the get's value."""
-    handles = [ga.at[u, lo:lo + n].put_nb(np.arange(n, dtype=np.float32))
-               for u, lo, n in PUTS]
+def _epoch_and_get(ga, asarray=np.asarray):
+    """One put_nb epoch of float32 payloads made by ``asarray``,
+    completed, then a blocking get of its third put; returns the get's
+    value."""
+    handles = [ga.at[u, lo:lo + n].put_nb(
+        asarray(np.arange(n, dtype=np.float32))) for u, lo, n in PUTS]
     ga.flush()
     dart_waitall(handles)
     return ga.at[0, 64:84].get()
@@ -65,7 +66,9 @@ def test_off_without_a_profiler_session(ga):
     assert tracing.totals() == {}
 
 
-def test_on_records_every_span_and_its_counters(ga, tmp_path, monkeypatch):
+@pytest.mark.parametrize("payload", ["numpy", "jax"])
+def test_on_records_every_span_and_its_counters(ga, tmp_path, monkeypatch,
+                                                payload):
     packed = []
     pack = sc.pack_descriptors
 
@@ -76,8 +79,9 @@ def test_on_records_every_span_and_its_counters(ga, tmp_path, monkeypatch):
     monkeypatch.setattr(sc, "pack_descriptors", spy)
     eng = ga.ctx.engine
     d0 = eng.dispatch_count
+    asarray = np.asarray if payload == "numpy" else jnp.asarray
     with jax.profiler.trace(str(tmp_path)):
-        value = _epoch_and_get(ga)
+        value = _epoch_and_get(ga, asarray)
     np.testing.assert_array_equal(value, np.arange(20))
     t = tracing.totals()
     assert set(SPANS) <= set(t)
@@ -97,10 +101,17 @@ def test_on_records_every_span_and_its_counters(ga, tmp_path, monkeypatch):
     assert t["dart.launch"]["h2d_bytes"] == (pdesc.nbytes + pflat.nbytes
                                             + gdesc.nbytes)
     assert t["dart.launch"]["miss"] in (0, 1, 2)
-    # host payloads: coerced up, staged back down, once per put
+    # one coerce and one stage per put; neither uploads.  A host payload
+    # of the ref's dtype stays on the host (no copy either way); a
+    # device payload is staged down once
     assert t["dart.coerce"]["n"] == t["dart.stage"]["n"] == len(PUTS)
-    assert t["dart.coerce"]["h2d_bytes"] == put_bytes
-    assert t["dart.stage"]["d2h_bytes"] == put_bytes
+    assert t["dart.coerce"]["h2d_bytes"] == 0
+    if payload == "numpy":
+        assert t["dart.coerce"]["host"] == len(PUTS)
+        assert t["dart.stage"].get("d2h_bytes", 0) == 0
+    else:
+        assert t["dart.coerce"]["host"] == 0
+        assert t["dart.stage"]["d2h_bytes"] == put_bytes
     assert t["dart.enqueue"]["n"] == len(PUTS) + 1
     # the get: one copy of its (kb, seg) windows, one typed re-upload
     assert t["dart.d2h"]["d2h_bytes"] == gdesc.shape[0] * gseg
@@ -160,8 +171,8 @@ def test_each_session_starts_the_totals_afresh(ga, tmp_path, between):
     with jax.profiler.trace(str(tmp_path / "second")):
         ga.at[1, 0:4].put_nb(np.ones(4, np.float32)).wait()
     t = tracing.totals()
-    assert t["dart.coerce"]["n"] == 1
-    assert t["dart.coerce"]["h2d_bytes"] == 16
+    assert t["dart.coerce"]["n"] == t["dart.coerce"]["host"] == 1
+    assert t["dart.coerce"]["h2d_bytes"] == 0
     assert "dart.decode" not in t
     tracing.reset()
     assert tracing.totals() == {}
